@@ -15,6 +15,9 @@ Observability (repro.obs):
     interpret flag) after the suites finish;
   * ``--profile-dir DIR`` wraps the whole run in a ``jax.profiler``
     trace for TensorBoard/Perfetto inspection.
+
+Compiled programs persist across runs in JAX's compilation cache
+(`repro.compile_cache`: ``$JAX_COMPILATION_CACHE_DIR`` or ``.jax_cache/``).
 """
 import argparse
 import glob
@@ -48,9 +51,12 @@ def main() -> None:
                             paper_fig3_imbalanced, paper_fig4_pernode,
                             paper_table2, roofline, serve_bench, solve_bench,
                             step_kernel_bench, stream_bench)
+    from repro.compile_cache import enable_compile_cache
     from repro.obs.export import provenance, stamp_provenance, write_jsonl
     from repro.obs.metrics import Registry, perf_clock
     from repro.obs.spans import recording, span
+
+    enable_compile_cache()
 
     suites = {
         "table2": paper_table2.run,

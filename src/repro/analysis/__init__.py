@@ -24,7 +24,7 @@ those contracts static, checked on every CI push and pinned by
   J004  loop carries never silently downcast f64→f32 — the rtol-1e-9
         parity contract dies quietly otherwise;
   J005  operands and control flow feeding collectives under
-        ``check_rep=False`` are provably replicated — a device-varying
+        ``check_vma=False`` are provably replicated — a device-varying
         ``while`` predicate gating a collective is a deadlock
         (the async mask-schedule hazard).
 

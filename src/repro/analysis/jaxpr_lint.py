@@ -23,7 +23,7 @@ statically verified — no solver numerics run, only tracing. Rules:
   J004  No silent x64→f32 downcasts (`convert_element_type`) inside
         `while`/`scan` bodies — a downcast θ carry would quietly degrade
         the rtol-1e-9 parity contract round over round.
-  J005  Under `shard_map(..., check_rep=False)` (which disables JAX's own
+  J005  Under `shard_map(..., check_vma=False)` (which disables JAX's own
         replication checking — the Pallas and tol>0 paths), any
         `while_loop` predicate or `cond` branch index that gates
         collectives must be *provably replicated* across the mesh: a
@@ -67,7 +67,7 @@ from repro.obs.dispatch import count_pallas_dispatches
 __all__ = [
     "EntryPoint", "batched_entry_points", "count_pallas_dispatches",
     "lint_program", "run_pass", "spmd_entry_points", "synthetic_packed",
-    "walk_eqns",
+    "unverified_shard_maps", "walk_eqns",
 ]
 
 # Rounds used for the dispatch-contract traces (any small R > 1 works; the
@@ -277,7 +277,7 @@ def check_loop_downcasts(closed, where: str) -> list[Finding]:
 
 
 # --------------------------------------------------------------------------
-# J005 — replication analysis under check_rep=False
+# J005 — replication analysis under check_vma=False
 # --------------------------------------------------------------------------
 def _eqn_axis_names(eqn) -> set:
     names = eqn.params.get("axes", eqn.params.get("axis_name", ()))
@@ -335,7 +335,7 @@ def _rep_eqn(eqn, ins, axes, findings, where):
                 "jaxpr", "J005", where,
                 "while_loop predicate is not provably replicated across "
                 "the mesh but the body issues collectives — under "
-                "check_rep=False devices can disagree on the trip count "
+                "check_vma=False devices can disagree on the trip count "
                 "and deadlock the exchange"))
         return carry
     if name == "scan":
@@ -364,7 +364,7 @@ def _rep_eqn(eqn, ins, axes, findings, where):
                 "jaxpr", "J005", where,
                 "cond branch index is not provably replicated across the "
                 "mesh but a branch issues collectives — under "
-                "check_rep=False devices can take different branches and "
+                "check_vma=False devices can take different branches and "
                 "deadlock the exchange"))
         return [pred and all(col) for col in zip(*branch_outs)]
     # Generic call-like eqn (pjit, custom_jvp/vjp, remat, …): recurse when
@@ -376,15 +376,30 @@ def _rep_eqn(eqn, ins, axes, findings, where):
     return [all(ins) if ins else True] * n_out
 
 
+def _spec_axes(spec) -> set:
+    """Mesh axis names a PartitionSpec shards over (empty = replicated)."""
+    names = set()
+    for entry in spec:
+        if entry is None:
+            continue
+        names.update(entry if isinstance(entry, tuple) else (entry,))
+    return names
+
+
+def unverified_shard_maps(closed) -> list:
+    """The `shard_map` eqns traced with ``check_vma=False`` — the ones
+    JAX does not check and J005 must analyze."""
+    return [eqn for eqn, _frames in walk_eqns(closed)
+            if eqn.primitive.name == "shard_map"
+            and not eqn.params["check_vma"]]
+
+
 def check_replication(closed, where: str) -> list[Finding]:
     findings: list[Finding] = []
-    for eqn, _frames in walk_eqns(closed):
-        if eqn.primitive.name != "shard_map":
-            continue
-        if eqn.params.get("check_rep", True):
-            continue                  # jax's own rewrite already checks
+    for eqn in unverified_shard_maps(closed):
         axes = set(dict(eqn.params["mesh"].shape))
-        in_reps = [len(names) == 0 for names in eqn.params["in_names"]]
+        in_reps = [not (_spec_axes(spec) & axes)
+                   for spec in eqn.params["in_specs"]]
         _rep_propagate(_inner(eqn.params["jaxpr"]), in_reps, axes,
                        findings, where)
     # Nested fixpoint iterations can emit duplicates — dedupe, keep order.

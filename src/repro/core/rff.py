@@ -17,6 +17,10 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+# A TPU's default f32 matmul rounds operands to bf16, which moves a cos
+# argument of |ωᵀx| ~ 10 by ~0.04 rad; featurize at full f32 precision.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
@@ -74,7 +78,7 @@ def sample_rff(key: jax.Array, dim: int, num_frequencies: int,
 @partial(jax.jit, static_argnames=())
 def _featurize_cos_sin(omega: jax.Array, x: jax.Array) -> jax.Array:
     d = omega.shape[0]
-    proj = omega @ x                                   # [D, N]
+    proj = jnp.matmul(omega, x, precision=_HIGHEST)    # [D, N]
     scale = jnp.asarray(1.0 / jnp.sqrt(d), proj.dtype)
     return jnp.concatenate([jnp.cos(proj), jnp.sin(proj)], axis=0) * scale
 
@@ -83,7 +87,7 @@ def _featurize_cos_sin(omega: jax.Array, x: jax.Array) -> jax.Array:
 def _featurize_cos_bias(omega: jax.Array, bias: jax.Array,
                         x: jax.Array) -> jax.Array:
     d = omega.shape[0]
-    proj = omega @ x + bias[:, None]                   # [D, N]
+    proj = jnp.matmul(omega, x, precision=_HIGHEST) + bias[:, None]
     scale = jnp.sqrt(jnp.asarray(2.0 / d, proj.dtype))
     return jnp.cos(proj) * scale
 

@@ -84,6 +84,16 @@ __all__ = [
 # makes rounds-run independent of the chunk size, so the chunk only sets
 # how much work one while_loop iteration dispatches.
 _ASYNC_CHUNK_DEFAULT = 16
+# The fused chain scalar-prefetches its [R, J] activation table into SMEM:
+# 1 MiB per TPU v5e core, each row padded to 128 int32 lanes. One dispatch
+# takes at most the rounds whose table fills half of it (1024 rounds for
+# J <= 128); longer schedules run in chunks, which is bit-invariant.
+_SMEM_TABLE_BYTES = 512 * 1024
+
+
+def _max_fused_rounds(j_nodes: int) -> int:
+    lanes = -(-j_nodes // 128) * 128
+    return max(1, _SMEM_TABLE_BYTES // (4 * lanes))
 
 
 @jax.tree_util.register_pytree_node_class
@@ -259,11 +269,13 @@ def _async_solve_fused(packed, state, masks, thresholds, *, gossip,
     bit-exactly and the result is chunk-size bit-invariant. With
     ``trace`` the same dispatches also fill the per-(round, node)
     residual ([R, J] float) and broadcast-flag ([R, J] int32) blocks —
-    returned alongside θ, concatenated across chunks."""
+    returned alongside θ, concatenated across chunks. Chunks never
+    exceed `_max_fused_rounds`, whatever `chunk_rounds` asks for."""
     from repro.kernels.ops import dekrr_async_solve
 
     num_iters = int(masks.shape[0])
     j_nodes = int(masks.shape[1])
+    chunk_rounds = min(chunk_rounds or num_iters, _max_fused_rounds(j_nodes))
 
     def call(st, mask_tab, thr_tab):
         outs = dekrr_async_solve(
@@ -273,7 +285,7 @@ def _async_solve_fused(packed, state, masks, thresholds, *, gossip,
         st = AsyncGossipState(theta=outs[0], sent=outs[1], buffers=outs[2])
         return st, (outs[3], outs[4]) if trace else None
 
-    if chunk_rounds is None or chunk_rounds >= num_iters:
+    if chunk_rounds >= num_iters:
         state, tr = call(state, masks, thresholds)
         return (state.theta,) + tr if trace else state.theta
 
@@ -714,10 +726,10 @@ def make_async_spmd_solver(mesh: Mesh, axis_name: str,
             node_program, mesh=mesh,
             in_specs=(spec, spec, spec, spec, spec, spec, rep, rep, spec),
             out_specs=out_spec,
-            # tol path: jax 0.4.x's scan rule rejects the pmax-derived
-            # `converged` carry (replication changes across the carry);
-            # the error text itself prescribes check_rep=False there.
-            check_rep=(backend not in _PALLAS_BACKENDS and tol == 0.0),
+            # Same rule as `make_spmd_solver`: the varying-manual-axes
+            # check needs a `vma` on pallas_call outputs and rejects the
+            # tol path's while_loop carries; lint J005 covers both paths.
+            check_vma=(backend not in _PALLAS_BACKENDS and tol == 0.0),
         )
         return sharded(g, d, s, p, nbr_idx, nbr_mask, masks, thresholds,
                        theta0)

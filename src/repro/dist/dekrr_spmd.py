@@ -96,13 +96,8 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec
-
-try:  # jax >= 0.5 promotes shard_map out of experimental
-    from jax import shard_map
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
 
 from repro.analysis.vmem import check_index_table
 from repro.obs.spans import span
@@ -750,14 +745,20 @@ def _node_step(g: jax.Array, d: jax.Array, s: jax.Array, p: jax.Array,
     blocks, so the mask multiply is belt-and-braces; padded coordinates
     come out exactly 0.0 because the corresponding rows of g are zero.
     The multi-output branch is the same contraction per output column —
-    scalar targets keep the exact original trace.
+    scalar targets keep the exact original trace. Every product runs at
+    HIGHEST precision, as the Pallas round kernels do: a TPU's default
+    f32 matmul rounds its operands to bf16.
     """
+    hi = jax.lax.Precision.HIGHEST
     if theta.ndim == 1:
-        coupled = jnp.einsum("kab,kb->a", p, nbr_theta * nbr_mask[:, None])
+        coupled = jnp.einsum("kab,kb->a", p, nbr_theta * nbr_mask[:, None],
+                             precision=hi)
     else:
         coupled = jnp.einsum("kab,kbo->ao", p,
-                             nbr_theta * nbr_mask[:, None, None])
-    return g @ (d + s @ theta + coupled)
+                             nbr_theta * nbr_mask[:, None, None],
+                             precision=hi)
+    own = jnp.matmul(s, theta, precision=hi)
+    return jnp.matmul(g, d + own + coupled, precision=hi)
 
 
 _BACKENDS = ("xla", "pallas", "pallas_fused")
@@ -1210,13 +1211,14 @@ def make_spmd_solver(mesh: Mesh, axis_name: str, mode: str = "ppermute",
             node_program, mesh=mesh,
             in_specs=(spec, spec, spec, spec, spec, spec, spec),
             out_specs=(spec, spec, spec) if return_trace else (spec, spec),
-            # jax 0.4.x has no replication rule for pallas_call, and its
-            # scan rule rejects the pmax-derived `converged` carry of the
-            # tol path (replication changes across the carry — the error
-            # text itself prescribes check_rep=False); every operand and
-            # output here is explicitly sharded anyway (the per-device
-            # round counts are pmax-synchronized copies).
-            check_rep=(backend not in _PALLAS_BACKENDS and tol == 0.0),
+            # The varying-manual-axes check needs a `vma` on every
+            # pallas_call output, which the ops wrappers do not declare,
+            # and its while_loop rule rejects the tol path's carries (the
+            # trace buffer starts invariant and turns device-varying).
+            # Every operand and output here is explicitly sharded anyway;
+            # lint J005 (`repro.analysis.jaxpr_lint`) proves the
+            # collective-gating control replicated where the check is off.
+            check_vma=(backend not in _PALLAS_BACKENDS and tol == 0.0),
         )
         return sharded(g, d, s, p, nbr_idx, nbr_mask, theta0)
 

@@ -11,7 +11,7 @@ the table. This kernel runs the *entire* solve in one `pallas_call`:
       θ0 table    [T, D]        fetched once (constant index map)
       G_j, S_j    [1, D, D]     streamed per (r, j) step — the index map
       P_j         [1, K, D, D]  depends only on j, so the Pallas pipeline
-      d_j         [1, D]        double-buffers the HBM→VMEM block streams
+      d_j         [Dy, D]       double-buffers the HBM→VMEM block streams
                                 across steps and rounds
       scratch     2 × [T, D]    VMEM θ tables (even/odd round parity)
 
@@ -26,10 +26,12 @@ is inherent (the blocks do not fit in VMEM for production J·D²) and is
 hidden behind the MXU by the pipeline.
 
 The per-step arithmetic — scalar-prefetched slot-table neighbor gather,
-row-vector dot_general contractions, zero-padding closure — is identical
-to `dekrr_step._dekrr_step_kernel`; the parity suite pins this kernel to
-`solve_batched(backend="xla")` and the ragged reference at rtol 1e-9
-under x64 (`tests/test_kernels_dekrr_solve.py`).
+row-vector dot_general contractions, zero-padding closure — is the one
+`dekrr_step._eq19_update` body every round kernel shares; the parity
+suite pins this kernel to `solve_batched(backend="xla")` and the ragged
+reference at rtol 1e-9 under x64 (`tests/test_kernels_dekrr_solve.py`).
+Per-node blocks and the per-(round, node) trace rows follow the TPU
+block rules described in `repro.kernels.dekrr_step`.
 
 VMEM working set: 2·T·D (θ tables) + 2·(2 + K)·D² (double-buffered
 blocks) + 3·D vectors — for the paper's J ≤ 256, D ≤ 512, K = 4 at f32
@@ -59,15 +61,15 @@ to force one dispatch per round rides scalar prefetch instead:
 Their VMEM working sets are `estimate_dekrr_async_solve` /
 `estimate_dekrr_cheb_solve` in `repro.analysis.vmem`.
 
-Multi-output targets (Dy > 1) use the flattened-row layout of
-`repro.kernels.dekrr_step`: θ/sent/Δ tables and d rows arrive as
-[T·Dy, D] with table row t owning flat rows [t·Dy, (t+1)·Dy) (that
-node's θᵀ as a [Dy, D] block), staleness buffers as [B·Dy, D] with slot
-(j, k) at rows [(j·K + k)·Dy, ...). Every kernel derives Dy from the d
-block's sublane extent and scales its dynamic row reads; at Dy = 1 the
-traces are unchanged. The censor reduction max|new − sent| runs over the
-[Dy, D] block, i.e. the max over features AND outputs the async runtime
-documents.
+Multi-output targets (Dy > 1) use the layout of
+`repro.kernels.dekrr_step`: θ/sent/Δ tables arrive as [T·Dy, D] with
+table row t owning flat rows [t·Dy, (t+1)·Dy) (that node's θᵀ as a
+[Dy, D] block), staleness buffers as [B·Dy, D] with slot (j, k) at rows
+[(j·K + k)·Dy, ...), and d and the per-node outputs as [J, Dy, D]
+(buffers [J, K·Dy, D]). Every kernel derives Dy from the d block's
+sublane extent and scales its dynamic row reads. The censor reduction
+max|new − sent| runs over the [Dy, D] block, i.e. the max over features
+AND outputs the async runtime documents.
 """
 from __future__ import annotations
 
@@ -78,10 +80,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dekrr_step import dekrr_step_reference
-
-# (M v)ᵀ as a row vector: contract [1, D] with [D', D] over the second axis.
-_ROW_TIMES_MAT_T = (((1,), (1,)), ((), ()))
+from repro.kernels.dekrr_step import (_eq19_update, _rows, _set_lane,
+                                      _set_rows, _table_update,
+                                      dekrr_step_reference)
 
 
 def _dekrr_solve_kernel(nbr_idx_ref, self_idx_ref, nbr_mask_ref,
@@ -90,16 +91,17 @@ def _dekrr_solve_kernel(nbr_idx_ref, self_idx_ref, nbr_mask_ref,
     """One node's Eq. 19 update at grid position (round, node).
 
     Scalar prefetch (SMEM): nbr_idx [J, K] int32, self_idx [J] int32,
-    nbr_mask [J, K] int32. Tensor operands: theta0 [T, D] (full table,
-    fetched once), g/s [1, D, D], d [1, D], p [1, K, D, D]; out [1, D]
-    (node j's θ row, overwritten every round — the last round wins).
-    Scratch: tab_even/tab_odd [T, D] VMEM θ tables, alternating by round
-    parity.
+    nbr_mask [J, K] int32. Tensor operands: theta0 [T·Dy, D] (full table,
+    fetched once), g/s [1, D, D], d [Dy, D], p [1, K, D, D]; out [Dy, D]
+    (node j's θ rows, overwritten every round — the last round wins).
+    Scratch: tab_even/tab_odd [T·Dy, D] VMEM θ tables, alternating by
+    round parity.
 
-    With static ``trace`` set, a second output block res [1, 1] at grid
-    index (r, j) records max|new − θ_self| over the node's [Dy, D] block
-    — the per-(round, node) convergence residual, written by the same
-    grid step that computes the round (zero extra dispatches). Padded
+    With static ``trace`` set, a second output block res [1, J] — round
+    r's row of the [R, 1, J] residual array, resident across the node
+    axis — gets lane j = max|new − θ_self| over the node's [Dy, D] block:
+    the per-(round, node) convergence residual, written by the same grid
+    step that computes the round (zero extra dispatches). Padded
     coordinates are identically zero on both sides of the subtraction,
     so the max is exact over real coordinates.
     """
@@ -110,9 +112,7 @@ def _dekrr_solve_kernel(nbr_idx_ref, self_idx_ref, nbr_mask_ref,
         out_res_ref = None
     r = pl.program_id(0)
     j = pl.program_id(1)
-    num_slots = nbr_idx_ref.shape[1]
     dy = d_ref.shape[0]
-    dtype = theta0_ref.dtype
 
     @pl.when(jnp.logical_and(r == 0, j == 0))
     def _init():
@@ -120,25 +120,14 @@ def _dekrr_solve_kernel(nbr_idx_ref, self_idx_ref, nbr_mask_ref,
         tab_even_ref[...] = theta0_ref[...]
         tab_odd_ref[...] = theta0_ref[...]
 
-    def row_times(rows, mat):
-        # rows [Dy, D] · mat [D', D]ᵀ → [Dy, D'] == (mat @ rows.T).T
-        return jax.lax.dot_general(
-            rows, mat, _ROW_TIMES_MAT_T,
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=dtype)
-
     def round_body(read_ref, write_ref):
-        theta_self = read_ref[pl.ds(self_idx_ref[j] * dy, dy), :]  # [Dy, D]
-        acc = d_ref[...] + row_times(theta_self, s_ref[0])       # d + S θ
-        for k in range(num_slots):                               # K unroll
-            theta_k = read_ref[pl.ds(nbr_idx_ref[j, k] * dy, dy), :]
-            mask_k = nbr_mask_ref[j, k].astype(dtype)
-            acc += row_times(theta_k, p_ref[0, k]) * mask_k      # Σ m P θ
-        new = row_times(acc, g_ref[0])                           # G (…)
-        write_ref[pl.ds(self_idx_ref[j] * dy, dy), :] = new
+        theta_self, new = _table_update(j, nbr_idx_ref, self_idx_ref,
+                                        nbr_mask_ref, read_ref, g_ref,
+                                        d_ref, s_ref, p_ref)
+        _set_rows(write_ref, self_idx_ref[j] * dy, new)
         out_ref[...] = new
         if trace:
-            out_res_ref[0, 0] = jnp.max(jnp.abs(new - theta_self))
+            _set_lane(out_res_ref, j, jnp.max(jnp.abs(new - theta_self)))
 
     even_round = r % 2 == 0
 
@@ -151,28 +140,37 @@ def _dekrr_solve_kernel(nbr_idx_ref, self_idx_ref, nbr_mask_ref,
         round_body(tab_odd_ref, tab_even_ref)
 
 
+def _node_rows(dy: int, d_feat: int, rows: int = 1):
+    """BlockSpec of node j's [rows·Dy, D] block of a [J, rows·Dy, D]
+    array on a (round, node) grid."""
+    return pl.BlockSpec((None, rows * dy, d_feat), lambda r, j, *_: (j, 0, 0))
+
+
+def _round_row(j_nodes: int):
+    """BlockSpec of round r's [1, J] row of an [R, 1, J] per-(round, node)
+    array — resident across the node axis, filled lane by lane."""
+    return pl.BlockSpec((None, 1, j_nodes), lambda r, j, *_: (r, 0, 0))
+
+
 def dekrr_solve_pallas(g: jax.Array, d: jax.Array, s: jax.Array,
                        p: jax.Array, theta: jax.Array, nbr_idx: jax.Array,
                        self_idx: jax.Array, nbr_mask: jax.Array, *,
-                       num_rounds: int, dy: int = 1, trace: bool = False,
+                       num_rounds: int, trace: bool = False,
                        interpret: bool = False) -> jax.Array:
     """Raw pallas_call. All dims must already be padded/aligned:
 
-      g/s [J, D, D], d [J·Dy, D], p [J, K, D, D] with K ≥ 1 and D a
+      g/s [J, D, D], d [J, Dy, D], p [J, K, D, D] with K ≥ 1 and D a
       multiple of 128; theta [T·Dy, D] with T·Dy padded to a multiple of
       8; nbr_idx [J, K] int32 *table* rows (pre-flattening); self_idx [J]
-      int32 (distinct rows); nbr_mask [J, K] int32; num_rounds ≥ 1 static;
-      dy ≥ 1 static (1 = scalar targets, today's layout).
-    Returns the θ rows after `num_rounds` Jacobi rounds, [J·Dy, D] (rows
-    [r·Dy, (r+1)·Dy) for node r — callers with T ≠ J re-assemble their
-    table themselves). With ``trace`` set, returns (θ rows, res [R, J])
-    where res[r, j] = max|Δθ_j| of round r — same single dispatch.
+      int32 (distinct rows); nbr_mask [J, K] int32; num_rounds ≥ 1 static.
+    Returns the θ rows after `num_rounds` Jacobi rounds, [J, Dy, D]
+    (callers with T ≠ J re-assemble their table themselves). With
+    ``trace`` set, returns (θ rows, res [R, 1, J]) where res[r, 0, j] =
+    max|Δθ_j| of round r — same single dispatch.
     """
-    j_nodes = d.shape[0] // dy
-    d_feat = d.shape[1]
+    j_nodes, dy, d_feat = d.shape
     k_slots = p.shape[1]
     t_rows = theta.shape[0]
-    assert d.shape[0] % dy == 0, (d.shape, dy)
     assert d_feat % 128 == 0 and t_rows % 8 == 0, (d_feat, t_rows)
     assert k_slots >= 1, "pad the slot axis to K >= 1 (zero P blocks)"
     assert num_rounds >= 1, "num_rounds must be a positive static int"
@@ -183,23 +181,20 @@ def dekrr_solve_pallas(g: jax.Array, d: jax.Array, s: jax.Array,
         in_specs=[
             pl.BlockSpec((t_rows, d_feat), lambda r, j, *_: (0, 0)),  # θ0
             pl.BlockSpec((1, d_feat, d_feat), lambda r, j, *_: (j, 0, 0)),
-            pl.BlockSpec((dy, d_feat), lambda r, j, *_: (j, 0)),
+            _node_rows(dy, d_feat),
             pl.BlockSpec((1, d_feat, d_feat), lambda r, j, *_: (j, 0, 0)),
             pl.BlockSpec((1, k_slots, d_feat, d_feat),
                          lambda r, j, *_: (j, 0, 0, 0)),
         ],
-        out_specs=(
-            (pl.BlockSpec((dy, d_feat), lambda r, j, *_: (j, 0)),
-             pl.BlockSpec((1, 1), lambda r, j, *_: (r, j)))
-            if trace else
-            pl.BlockSpec((dy, d_feat), lambda r, j, *_: (j, 0))),
+        out_specs=((_node_rows(dy, d_feat), _round_row(j_nodes))
+                   if trace else _node_rows(dy, d_feat)),
         scratch_shapes=[
             pltpu.VMEM((t_rows, d_feat), theta.dtype),   # even-round table
             pltpu.VMEM((t_rows, d_feat), theta.dtype),   # odd-round table
         ],
     )
-    theta_shape = jax.ShapeDtypeStruct((j_nodes * dy, d_feat), theta.dtype)
-    res_shape = jax.ShapeDtypeStruct((num_rounds, j_nodes), theta.dtype)
+    theta_shape = jax.ShapeDtypeStruct((j_nodes, dy, d_feat), theta.dtype)
+    res_shape = jax.ShapeDtypeStruct((num_rounds, 1, j_nodes), theta.dtype)
     flops_per_node = 2 * (2 + k_slots) * d_feat * d_feat * dy
     return pl.pallas_call(
         functools.partial(_dekrr_solve_kernel, trace=trace),
@@ -229,9 +224,10 @@ def _dekrr_async_solve_kernel(nbr_idx_ref, nbr_mask_ref, active_ref, thr_ref,
     The whole COKE schedule is precomputed, so it rides scalar prefetch:
     nbr_idx [J, K] int32 (NODE ids, not table rows — self row of node j is
     row j), nbr_mask [J, K] int32, active [R, J] int32 activation table,
-    thr [R] float censor thresholds. Tensor operands: theta0/sent0 [T, D]
-    and buf0 [B, D] initial state (constant index maps, fetched once),
-    g/s [1, D, D], d [1, D], p [1, K, D, D] streamed per (r, j).
+    thr [R] float censor thresholds. Tensor operands: theta0/sent0
+    [T·Dy, D] and buf0 [B·Dy, D] initial state (constant index maps,
+    fetched once), g/s [1, D, D], d [Dy, D], p [1, K, D, D] streamed per
+    (r, j).
 
     State lives in scratch across the whole grid: two round-parity θ
     tables (Jacobi semantics, as in the sync kernel), a sent table and a
@@ -258,13 +254,13 @@ def _dekrr_async_solve_kernel(nbr_idx_ref, nbr_mask_ref, active_ref, thr_ref,
     on the [θ; buffers] concat table, so the chain is bit-for-bit the
     scanned per-round "pallas" backend.
 
-    With static ``trace`` set, two more output blocks at grid index
-    (r, j) — res [1, 1] float and bc [1, 1] int32, shapes [R + 1, J] —
-    record max|new − θ_self| and the round's broadcast flag for active
-    nodes (0/0 for inactive nodes and the delivery-flush step). Written
-    by the same grid steps: zero extra dispatches. The caller slices off
-    the flush row and derives the wire series (deliveries, bytes) from
-    the bc flags + slot tables in plain XLA.
+    With static ``trace`` set, two more output blocks — round r's [1, J]
+    rows of res (float) and bc (int32), arrays [R + 1, 1, J] — get lane
+    j = max|new − θ_self| and the round's broadcast flag for active nodes
+    (0/0 for inactive nodes and the delivery-flush step). Written by the
+    same grid steps: zero extra dispatches. The caller slices off the
+    flush row and derives the wire series (deliveries, bytes) from the bc
+    flags + slot tables in plain XLA.
     """
     if trace:
         (out_theta_ref, out_sent_ref, out_buf_ref, out_res_ref, out_bc_ref,
@@ -278,7 +274,6 @@ def _dekrr_async_solve_kernel(nbr_idx_ref, nbr_mask_ref, active_ref, thr_ref,
     j = pl.program_id(1)
     num_slots = nbr_idx_ref.shape[1]
     dy = d_ref.shape[0]
-    dtype = theta0_ref.dtype
 
     @pl.when(jnp.logical_and(r == 0, j == 0))
     def _init():
@@ -287,12 +282,8 @@ def _dekrr_async_solve_kernel(nbr_idx_ref, nbr_mask_ref, active_ref, thr_ref,
         sent_ref[...] = sent0_ref[...]
         buf_ref[...] = buf0_ref[...]
 
-    def row_times(rows, mat):
-        # rows [Dy, D] · mat [D', D]ᵀ → [Dy, D'] == (mat @ rows.T).T
-        return jax.lax.dot_general(
-            rows, mat, _ROW_TIMES_MAT_T,
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=dtype)
+    def slot_row(k):
+        return (j * num_slots + k) * dy
 
     def deliver(read_tab, fl_read):
         for k in range(num_slots):
@@ -304,46 +295,43 @@ def _dekrr_async_solve_kernel(nbr_idx_ref, nbr_mask_ref, active_ref, thr_ref,
 
             @pl.when(cond)
             def _recv(k=k, nb=nb):
-                buf_ref[pl.ds((j * num_slots + k) * dy, dy), :] = \
-                    read_tab[pl.ds(nb * dy, dy), :]
+                _set_rows(buf_ref, slot_row(k), _rows(read_tab, nb * dy, dy))
 
     def compute(read_tab, write_tab, fl_write):
         is_active = active_ref[r, j] != 0
 
         @pl.when(is_active)
         def _update():
-            theta_self = read_tab[pl.ds(j * dy, dy), :]          # [Dy, D]
-            acc = d_ref[...] + row_times(theta_self, s_ref[0])   # d + S θ
-            for k in range(num_slots):                           # K unroll
-                theta_k = buf_ref[pl.ds((j * num_slots + k) * dy, dy), :]
-                mask_k = nbr_mask_ref[j, k].astype(dtype)
-                acc += row_times(theta_k, p_ref[0, k]) * mask_k  # Σ m P θ
-            new = row_times(acc, g_ref[0])                       # G (…)
-            write_tab[pl.ds(j * dy, dy), :] = new
+            theta_self = _rows(read_tab, j * dy, dy)             # [Dy, D]
+            new = _eq19_update(
+                j, theta_self, lambda k: _rows(buf_ref, slot_row(k), dy),
+                nbr_mask_ref, g_ref, d_ref, s_ref, p_ref)
+            _set_rows(write_tab, j * dy, new)
             out_theta_ref[...] = new
             if trace:
-                out_res_ref[0, 0] = jnp.max(jnp.abs(new - theta_self))
+                _set_lane(out_res_ref, j,
+                          jnp.max(jnp.abs(new - theta_self)))
             if censored:
                 # max over features AND outputs — the [Dy, D] block
-                delta = jnp.max(jnp.abs(new - sent_ref[pl.ds(j * dy, dy), :]))
+                delta = jnp.max(jnp.abs(new - _rows(sent_ref, j * dy, dy)))
                 bc = delta > thr_ref[r]
                 fl_write[j] = bc.astype(jnp.int32)
                 if trace:
-                    out_bc_ref[0, 0] = bc.astype(jnp.int32)
+                    _set_lane(out_bc_ref, j, bc)
 
                 @pl.when(bc)
                 def _bcast():
-                    sent_ref[pl.ds(j * dy, dy), :] = new
+                    _set_rows(sent_ref, j * dy, new)
             else:
                 fl_write[j] = jnp.int32(1)
                 if trace:
-                    out_bc_ref[0, 0] = jnp.int32(1)
-                sent_ref[pl.ds(j * dy, dy), :] = new
+                    _set_lane(out_bc_ref, j, jnp.int32(1))
+                _set_rows(sent_ref, j * dy, new)
 
         @pl.when(jnp.logical_not(is_active))
         def _passthrough():
-            cur = read_tab[pl.ds(j * dy, dy), :]
-            write_tab[pl.ds(j * dy, dy), :] = cur
+            cur = _rows(read_tab, j * dy, dy)
+            _set_rows(write_tab, j * dy, cur)
             out_theta_ref[...] = cur
             fl_write[j] = jnp.int32(0)
 
@@ -351,8 +339,8 @@ def _dekrr_async_solve_kernel(nbr_idx_ref, nbr_mask_ref, active_ref, thr_ref,
         if trace:
             # Defaults every grid step (inactive nodes and the flush row
             # record 0); the active-node update overwrites both.
-            out_res_ref[0, 0] = jnp.zeros((), dtype)
-            out_bc_ref[0, 0] = jnp.int32(0)
+            _set_lane(out_res_ref, j, jnp.zeros((), out_res_ref.dtype))
+            _set_lane(out_bc_ref, j, jnp.int32(0))
 
         @pl.when(r >= 1)
         def _deliver():
@@ -364,11 +352,10 @@ def _dekrr_async_solve_kernel(nbr_idx_ref, nbr_mask_ref, active_ref, thr_ref,
 
         @pl.when(r == num_rounds)
         def _flush():
-            out_theta_ref[...] = read_tab[pl.ds(j * dy, dy), :]
+            out_theta_ref[...] = _rows(read_tab, j * dy, dy)
 
-        out_sent_ref[...] = sent_ref[pl.ds(j * dy, dy), :]
-        out_buf_ref[...] = buf_ref[pl.ds(j * num_slots * dy,
-                                         num_slots * dy), :]
+        out_sent_ref[...] = _rows(sent_ref, j * dy, dy)
+        out_buf_ref[...] = _rows(buf_ref, slot_row(0), num_slots * dy)
 
     even_round = r % 2 == 0
 
@@ -387,31 +374,27 @@ def dekrr_async_solve_pallas(g: jax.Array, d: jax.Array, s: jax.Array,
                              nbr_idx: jax.Array, nbr_mask: jax.Array,
                              active_tab: jax.Array, thresholds: jax.Array,
                              *, censored: bool, edge_gossip: bool,
-                             dy: int = 1, trace: bool = False,
-                             interpret: bool = False
+                             trace: bool = False, interpret: bool = False
                              ) -> tuple[jax.Array, ...]:
     """Raw pallas_call. All dims must already be padded/aligned:
 
-      g/s [J, D, D], d [J·Dy, D], p [J, K, D, D] with K ≥ 1 and D a
+      g/s [J, D, D], d [J, Dy, D], p [J, K, D, D] with K ≥ 1 and D a
       multiple of 128; theta/sent [T·Dy, D] with T ≥ J and T·Dy padded to
       a multiple of 8 (rows [j·Dy, (j+1)·Dy) = node j); buffers [B·Dy, D]
       with B ≥ J·K, B·Dy a multiple of 8 (rows [(j·K + k)·Dy, ...) = slot
       (j, k)); nbr_idx/nbr_mask [J, K] int32 with entries < J;
-      active_tab [R, J] int32 with R ≥ 1 static; thresholds [R] float;
-      dy ≥ 1 static (1 = scalar targets, today's layout).
-    Returns the post-schedule (θ rows [J·Dy, D], sent rows [J·Dy, D],
-    buffer rows [J·K·Dy, D]). With ``trace`` set, appends
-    (res [R + 1, J] float, bc [R + 1, J] int32) — per-(round, node)
+      active_tab [R, J] int32 with R ≥ 1 static; thresholds [R] float.
+    Returns the post-schedule (θ rows [J, Dy, D], sent rows [J, Dy, D],
+    buffer rows [J, K·Dy, D]). With ``trace`` set, appends
+    (res [R + 1, 1, J] float, bc [R + 1, 1, J] int32) — per-(round, node)
     max|Δθ| and broadcast flags, last row (delivery flush) all-zero —
     still one dispatch.
     """
-    j_nodes = d.shape[0] // dy
-    d_feat = d.shape[1]
+    j_nodes, dy, d_feat = d.shape
     k_slots = p.shape[1]
     t_rows = theta.shape[0]
     b_rows = buffers.shape[0]
     num_rounds = active_tab.shape[0]
-    assert d.shape[0] % dy == 0, (d.shape, dy)
     assert d_feat % 128 == 0 and t_rows % 8 == 0 and b_rows % 8 == 0, \
         (d_feat, t_rows, b_rows)
     assert sent.shape == theta.shape, (sent.shape, theta.shape)
@@ -427,20 +410,17 @@ def dekrr_async_solve_pallas(g: jax.Array, d: jax.Array, s: jax.Array,
             pl.BlockSpec((t_rows, d_feat), lambda r, j, *_: (0, 0)),  # sent0
             pl.BlockSpec((b_rows, d_feat), lambda r, j, *_: (0, 0)),  # buf0
             pl.BlockSpec((1, d_feat, d_feat), lambda r, j, *_: (j, 0, 0)),
-            pl.BlockSpec((dy, d_feat), lambda r, j, *_: (j, 0)),
+            _node_rows(dy, d_feat),
             pl.BlockSpec((1, d_feat, d_feat), lambda r, j, *_: (j, 0, 0)),
             pl.BlockSpec((1, k_slots, d_feat, d_feat),
                          lambda r, j, *_: (j, 0, 0, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((dy, d_feat), lambda r, j, *_: (j, 0)),      # θ
-            pl.BlockSpec((dy, d_feat), lambda r, j, *_: (j, 0)),      # sent
-            pl.BlockSpec((k_slots * dy, d_feat),
-                         lambda r, j, *_: (j, 0)),                    # buf
-        ) + ((
-            pl.BlockSpec((1, 1), lambda r, j, *_: (r, j)),            # res
-            pl.BlockSpec((1, 1), lambda r, j, *_: (r, j)),            # bc
-        ) if trace else ()),
+            _node_rows(dy, d_feat),                                   # θ
+            _node_rows(dy, d_feat),                                   # sent
+            _node_rows(dy, d_feat, rows=k_slots),                     # buf
+        ) + ((_round_row(j_nodes), _round_row(j_nodes))               # res, bc
+             if trace else ()),
         scratch_shapes=[
             pltpu.VMEM((t_rows, d_feat), theta.dtype),   # even-round table
             pltpu.VMEM((t_rows, d_feat), theta.dtype),   # odd-round table
@@ -454,17 +434,17 @@ def dekrr_async_solve_pallas(g: jax.Array, d: jax.Array, s: jax.Array,
         _dekrr_async_solve_kernel, censored=censored,
         edge_gossip=edge_gossip, num_rounds=num_rounds, trace=trace)
     flops_per_node = 2 * (2 + k_slots) * d_feat * d_feat * dy
+    rows = jax.ShapeDtypeStruct((j_nodes, dy, d_feat), theta.dtype)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=(
-            jax.ShapeDtypeStruct((j_nodes * dy, d_feat), theta.dtype),
-            jax.ShapeDtypeStruct((j_nodes * dy, d_feat), theta.dtype),
-            jax.ShapeDtypeStruct((j_nodes * k_slots * dy, d_feat),
+            rows, rows,
+            jax.ShapeDtypeStruct((j_nodes, k_slots * dy, d_feat),
                                  theta.dtype),
         ) + ((
-            jax.ShapeDtypeStruct((num_rounds + 1, j_nodes), theta.dtype),
-            jax.ShapeDtypeStruct((num_rounds + 1, j_nodes), jnp.int32),
+            jax.ShapeDtypeStruct((num_rounds + 1, 1, j_nodes), theta.dtype),
+            jax.ShapeDtypeStruct((num_rounds + 1, 1, j_nodes), jnp.int32),
         ) if trace else ()),
         cost_estimate=pl.CostEstimate(
             flops=num_rounds * j_nodes * flops_per_node,
@@ -490,7 +470,7 @@ def _dekrr_cheb_solve_kernel(nbr_idx_ref, self_idx_ref, nbr_mask_ref,
     Identical layout to the plain fused solve — parity-alternating θ
     tables, scalar-prefetched slot tables — plus the precomputed (α, β)
     schedule (`repro.core.acceleration.chebyshev_coefficients`) as two
-    [R] float prefetch vectors and a [J', D] VMEM table holding each
+    [R] float prefetch vectors and a [J'·Dy, D] VMEM table holding each
     node's two-term recurrence direction state p (owner-only access, no
     parity; Δ_k = α_k p_k):
 
@@ -502,10 +482,10 @@ def _dekrr_cheb_solve_kernel(nbr_idx_ref, self_idx_ref, nbr_mask_ref,
     callers can chain bit-exactly — the exact recurrence
     `repro.core.acceleration.chebyshev_scan` runs on the host/XLA paths.
 
-    With static ``trace`` set, one more output block res [1, 1] at grid
-    index (r, j) records max|θ_new − θ_j| (the accelerated update's
-    actual step α_r p_j, not the F-residual) — shape [R, J], written by
-    the same grid steps, zero extra dispatches.
+    With static ``trace`` set, one more output block — round r's [1, J]
+    row of res [R, 1, J] — gets lane j = max|θ_new − θ_j| (the
+    accelerated update's actual step α_r p_j, not the F-residual),
+    written by the same grid steps, zero extra dispatches.
     """
     if trace:
         (out_theta_ref, out_delta_ref, out_res_ref, tab_even_ref,
@@ -516,9 +496,7 @@ def _dekrr_cheb_solve_kernel(nbr_idx_ref, self_idx_ref, nbr_mask_ref,
         out_res_ref = None
     r = pl.program_id(0)
     j = pl.program_id(1)
-    num_slots = nbr_idx_ref.shape[1]
     dy = d_ref.shape[0]
-    dtype = theta0_ref.dtype
 
     @pl.when(jnp.logical_and(r == 0, j == 0))
     def _init():
@@ -526,30 +504,19 @@ def _dekrr_cheb_solve_kernel(nbr_idx_ref, self_idx_ref, nbr_mask_ref,
         tab_odd_ref[...] = theta0_ref[...]
         delta_ref[...] = delta0_ref[...]
 
-    def row_times(rows, mat):
-        # rows [Dy, D] · mat [D', D]ᵀ → [Dy, D'] == (mat @ rows.T).T
-        return jax.lax.dot_general(
-            rows, mat, _ROW_TIMES_MAT_T,
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=dtype)
-
     def round_body(read_ref, write_ref):
-        theta_self = read_ref[pl.ds(self_idx_ref[j] * dy, dy), :]  # [Dy, D]
-        acc = d_ref[...] + row_times(theta_self, s_ref[0])       # d + S θ
-        for k in range(num_slots):                               # K unroll
-            theta_k = read_ref[pl.ds(nbr_idx_ref[j, k] * dy, dy), :]
-            mask_k = nbr_mask_ref[j, k].astype(dtype)
-            acc += row_times(theta_k, p_ref[0, k]) * mask_k      # Σ m P θ
-        new = row_times(acc, g_ref[0])                           # F(θ)_j
+        theta_self, new = _table_update(j, nbr_idx_ref, self_idx_ref,
+                                        nbr_mask_ref, read_ref, g_ref,
+                                        d_ref, s_ref, p_ref)  # F(θ)_j
         resid = new - theta_self
-        p_new = resid + beta_ref[r] * delta_ref[pl.ds(j * dy, dy), :]
+        p_new = resid + beta_ref[r] * _rows(delta_ref, j * dy, dy)
         th_new = theta_self + alpha_ref[r] * p_new
-        write_ref[pl.ds(self_idx_ref[j] * dy, dy), :] = th_new
-        delta_ref[pl.ds(j * dy, dy), :] = p_new
+        _set_rows(write_ref, self_idx_ref[j] * dy, th_new)
+        _set_rows(delta_ref, j * dy, p_new)
         out_theta_ref[...] = th_new
         out_delta_ref[...] = p_new
         if trace:
-            out_res_ref[0, 0] = jnp.max(jnp.abs(th_new - theta_self))
+            _set_lane(out_res_ref, j, jnp.max(jnp.abs(th_new - theta_self)))
 
     even_round = r % 2 == 0
 
@@ -567,24 +534,21 @@ def dekrr_cheb_solve_pallas(g: jax.Array, d: jax.Array, s: jax.Array,
                             delta: jax.Array, nbr_idx: jax.Array,
                             self_idx: jax.Array, nbr_mask: jax.Array,
                             alphas: jax.Array, betas: jax.Array, *,
-                            dy: int = 1, trace: bool = False,
-                            interpret: bool = False
+                            trace: bool = False, interpret: bool = False
                             ) -> tuple[jax.Array, ...]:
-    """Raw pallas_call. Same operand contract as `dekrr_solve_pallas`
-    (Dy-flattened θ/d rows when dy > 1), plus delta [J'·Dy, D] (J' ≥ J,
-    J'·Dy a multiple of 8, rows [j·Dy, (j+1)·Dy) = node j's direction
-    state p) and the [R] float (α, β) schedule with R ≥ 1 static.
-    Returns the (θ rows [J·Dy, D], p rows [J·Dy, D]) after R Chebyshev
-    rounds. With ``trace`` set, appends res [R, J] — per-(round, node)
-    max|Δθ| of the accelerated update — same single dispatch.
+    """Raw pallas_call. Same operand contract as `dekrr_solve_pallas`,
+    plus delta [J'·Dy, D] (J' ≥ J, J'·Dy a multiple of 8, rows
+    [j·Dy, (j+1)·Dy) = node j's direction state p) and the [R] float
+    (α, β) schedule with R ≥ 1 static. Returns the (θ rows [J, Dy, D],
+    p rows [J, Dy, D]) after R Chebyshev rounds. With ``trace`` set,
+    appends res [R, 1, J] — per-(round, node) max|Δθ| of the accelerated
+    update — same single dispatch.
     """
-    j_nodes = d.shape[0] // dy
-    d_feat = d.shape[1]
+    j_nodes, dy, d_feat = d.shape
     k_slots = p.shape[1]
     t_rows = theta.shape[0]
     j_rows = delta.shape[0]
     num_rounds = alphas.shape[0]
-    assert d.shape[0] % dy == 0, (d.shape, dy)
     assert d_feat % 128 == 0 and t_rows % 8 == 0 and j_rows % 8 == 0, \
         (d_feat, t_rows, j_rows)
     assert j_rows >= j_nodes * dy, (j_rows, j_nodes, dy)
@@ -599,17 +563,15 @@ def dekrr_cheb_solve_pallas(g: jax.Array, d: jax.Array, s: jax.Array,
             pl.BlockSpec((t_rows, d_feat), lambda r, j, *_: (0, 0)),  # θ0
             pl.BlockSpec((j_rows, d_feat), lambda r, j, *_: (0, 0)),  # Δ0
             pl.BlockSpec((1, d_feat, d_feat), lambda r, j, *_: (j, 0, 0)),
-            pl.BlockSpec((dy, d_feat), lambda r, j, *_: (j, 0)),
+            _node_rows(dy, d_feat),
             pl.BlockSpec((1, d_feat, d_feat), lambda r, j, *_: (j, 0, 0)),
             pl.BlockSpec((1, k_slots, d_feat, d_feat),
                          lambda r, j, *_: (j, 0, 0, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((dy, d_feat), lambda r, j, *_: (j, 0)),      # θ
-            pl.BlockSpec((dy, d_feat), lambda r, j, *_: (j, 0)),      # Δ
-        ) + ((
-            pl.BlockSpec((1, 1), lambda r, j, *_: (r, j)),            # res
-        ) if trace else ()),
+            _node_rows(dy, d_feat),                                   # θ
+            _node_rows(dy, d_feat),                                   # Δ
+        ) + ((_round_row(j_nodes),) if trace else ()),                # res
         scratch_shapes=[
             pltpu.VMEM((t_rows, d_feat), theta.dtype),   # even-round table
             pltpu.VMEM((t_rows, d_feat), theta.dtype),   # odd-round table
@@ -617,14 +579,12 @@ def dekrr_cheb_solve_pallas(g: jax.Array, d: jax.Array, s: jax.Array,
         ],
     )
     flops_per_node = 2 * (2 + k_slots) * d_feat * d_feat * dy
+    rows = jax.ShapeDtypeStruct((j_nodes, dy, d_feat), theta.dtype)
     return pl.pallas_call(
         functools.partial(_dekrr_cheb_solve_kernel, trace=trace),
         grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((j_nodes * dy, d_feat), theta.dtype),
-            jax.ShapeDtypeStruct((j_nodes * dy, d_feat), theta.dtype),
-        ) + ((
-            jax.ShapeDtypeStruct((num_rounds, j_nodes), theta.dtype),
+        out_shape=(rows, rows) + ((
+            jax.ShapeDtypeStruct((num_rounds, 1, j_nodes), theta.dtype),
         ) if trace else ()),
         cost_estimate=pl.CostEstimate(
             flops=num_rounds * j_nodes * flops_per_node,

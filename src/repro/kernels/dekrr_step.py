@@ -16,7 +16,7 @@ HBM→VMEM and θ never leaves VMEM:
                               reduction operand; T = J in batched mode)
       G_j, S_j  [1, D, D]     streamed per step
       P_j       [1, K, D, D]  streamed per step
-      d_j       [1, D]        streamed per step
+      d_j       [Dy, D]       streamed per step (block of d [J, Dy, D])
     per step j: acc  = d_j + S_j θ_{self(j)}            (MXU)
                 acc += Σ_k m_{j,k} · P_{j,k} θ_{row(j,k)}   (MXU, K unrolled)
                 out_j = G_j acc                         (MXU)
@@ -25,6 +25,13 @@ The neighbor gather is done *inside* the kernel with the slot table: the
 int32 tables `nbr_idx` [J, K] / `self_idx` [J] arrive via scalar prefetch
 (`PrefetchScalarGridSpec`, SMEM) and index dynamic [1, D] row reads of the
 VMEM θ table — no one-hot matmul, no gathered [J, K, D] tensor in HBM.
+
+TPU tiling. Mosaic takes a block whose last two dims are multiples of
+(8, 128) or equal to the array's. Per-node row blocks therefore ride a
+leading node axis — d and the output are [J, Dy, D] with block
+(None, Dy, D) — and the resident θ table is read one row at a time: a
+dynamic sublane offset is accepted for a 1-row slice, but a Dy-row slice
+at t·Dy is not provably 8-aligned (`_rows` / `_set_rows`).
 
 Decoupling the θ-table row from the node id (`self_idx`) lets the SPMD
 per-device node program reuse the identical kernel: a device holding one
@@ -39,13 +46,12 @@ belt-and-braces. Vectors are kept as [1, D] rows and every product is a
 dot_general contracting the matrix's second axis (computing (M v)ᵀ without
 materializing any transpose).
 
-Multi-output targets (Dy > 1) keep the same kernel: θ tables and d/out
-rows arrive *flattened* along the sublane axis as [T·Dy, D] / [J·Dy, D],
-with table row t owning the Dy consecutive rows [t·Dy, (t+1)·Dy) (θᵀ for
-that node, laid out [Dy, D]). The kernel derives Dy from the d block's
-sublane extent and scales every dynamic row read by it; at Dy = 1 the
-index arithmetic degenerates to the scalar kernel's and the trace is
-unchanged. A [Dy, D] row block through the same dot_generals is exactly
+Multi-output targets (Dy > 1) keep the same kernel: the θ table arrives
+*flattened* along the sublane axis as [T·Dy, D], with table row t owning
+the Dy consecutive rows [t·Dy, (t+1)·Dy) (θᵀ for that node, laid out
+[Dy, D]); d and the output carry the [J, Dy, D] node axis. The kernel
+derives Dy from the d block's sublane extent and scales every dynamic row
+read by it. A [Dy, D] row block through the same dot_generals is exactly
 the per-output loop batched on the free axis — no arithmetic changes.
 
 VMEM working set per step: T·D (θ, Dy folded into T) + (2 + K)·D²
@@ -77,32 +83,65 @@ from jax.experimental.pallas import tpu as pltpu
 _ROW_TIMES_MAT_T = (((1,), (1,)), ((), ()))
 
 
-def _eq19_update(j, nbr_idx_ref, self_idx_ref, nbr_mask_ref,
-                 theta_ref, g_ref, d_ref, s_ref, p_ref):
-    """Node j's Eq. 19 update as a [Dy, D] row block — the arithmetic
-    shared by the unmasked and activation-masked round kernels (one body,
-    so the masked variant's active branch can never drift from the
-    synchronous kernel it must reproduce bit-for-bit at full activation).
-    Dy is the d block's sublane extent (1 for scalar targets); θ-table
-    row t lives at flat rows [t·Dy, (t+1)·Dy)."""
-    num_slots = nbr_idx_ref.shape[1]
-    dy = d_ref.shape[0]
-    dtype = theta_ref.dtype
+def _row_times(rows, mat):
+    """rows [Dy, D] · mat [D', D]ᵀ → [Dy, D'] == (mat @ rows.T).T"""
+    return jax.lax.dot_general(
+        rows, mat, _ROW_TIMES_MAT_T,
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=rows.dtype)
 
-    def row_times(rows, mat):
-        # rows [Dy, D] · mat [D', D]ᵀ → [Dy, D'] == (mat @ rows.T).T
-        return jax.lax.dot_general(
-            rows, mat, _ROW_TIMES_MAT_T,
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=dtype)
 
-    theta_self = theta_ref[pl.ds(self_idx_ref[j] * dy, dy), :]   # [Dy, D]
-    acc = d_ref[...] + row_times(theta_self, s_ref[0])           # d + S θ
-    for k in range(num_slots):                               # K static unroll
-        theta_k = theta_ref[pl.ds(nbr_idx_ref[j, k] * dy, dy), :]
+def _rows(ref, start, n: int):
+    """Rows [start, start + n) of a 2-D VMEM ref as an [n, D] value, one
+    dynamic single-row load each (see the module docstring)."""
+    if n == 1:
+        return ref[pl.ds(start, 1), :]
+    return jnp.concatenate([ref[pl.ds(start + o, 1), :] for o in range(n)],
+                           axis=0)
+
+
+def _set_rows(ref, start, value) -> None:
+    """Store an [n, D] value at rows [start, start + n) of a 2-D VMEM ref,
+    one dynamic single-row store per row."""
+    for o in range(value.shape[0]):
+        ref[pl.ds(start + o, 1), :] = value[o:o + 1]
+
+
+def _set_lane(ref, lane, value) -> None:
+    """ref[0, lane] = value on a [1, W] VMEM block — a resident per-round
+    row that each node's grid step fills in turn (a (1, 1) block of an
+    [R, J] array is not a legal TPU block shape)."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, ref.shape, 1)
+    ref[...] = jnp.where(lanes == lane, value.astype(ref.dtype), ref[...])
+
+
+def _eq19_update(j, theta_self, nbr_rows, nbr_mask_ref, g_ref, d_ref, s_ref,
+                 p_ref):
+    """Node j's Eq. 19 update as a [Dy, D] row block — the one arithmetic
+    body every round kernel runs (sync, activation-masked, fused solve,
+    async chain, Chebyshev), so they can never drift apart.
+
+    theta_self [Dy, D] is the node's own θ; ``nbr_rows(k)`` returns slot
+    k's [Dy, D] neighbor θ (from the θ table, or from the async staleness
+    buffers)."""
+    dtype = theta_self.dtype
+    acc = d_ref[...] + _row_times(theta_self, s_ref[0])      # d + S θ
+    for k in range(nbr_mask_ref.shape[1]):                   # K static unroll
         mask_k = nbr_mask_ref[j, k].astype(dtype)
-        acc += row_times(theta_k, p_ref[0, k]) * mask_k      # Σ m P θ_nbr
-    return row_times(acc, g_ref[0])                          # G (…)
+        acc += _row_times(nbr_rows(k), p_ref[0, k]) * mask_k  # Σ m P θ_nbr
+    return _row_times(acc, g_ref[0])                          # G (…)
+
+
+def _table_update(j, nbr_idx_ref, self_idx_ref, nbr_mask_ref, table_ref,
+                  g_ref, d_ref, s_ref, p_ref):
+    """`_eq19_update` with self and neighbor θ read from a θ table through
+    the slot tables; returns (θ_self, new), both [Dy, D]."""
+    dy = d_ref.shape[0]
+    theta_self = _rows(table_ref, self_idx_ref[j] * dy, dy)
+    new = _eq19_update(
+        j, theta_self, lambda k: _rows(table_ref, nbr_idx_ref[j, k] * dy, dy),
+        nbr_mask_ref, g_ref, d_ref, s_ref, p_ref)
+    return theta_self, new
 
 
 def _dekrr_step_kernel(nbr_idx_ref, self_idx_ref, nbr_mask_ref,
@@ -110,12 +149,12 @@ def _dekrr_step_kernel(nbr_idx_ref, self_idx_ref, nbr_mask_ref,
     """One node's Eq. 19 update; grid position = node id.
 
     Scalar prefetch (SMEM): nbr_idx [J, K] int32, self_idx [J] int32,
-    nbr_mask [J, K] int32. Tensor operands: theta [T, D] (full table,
-    VMEM-resident), g/s [1, D, D], d [1, D], p [1, K, D, D]; out [1, D].
+    nbr_mask [J, K] int32. Tensor operands: theta [T·Dy, D] (full table,
+    VMEM-resident), g/s [1, D, D], d [Dy, D], p [1, K, D, D]; out [Dy, D].
     """
     j = pl.program_id(0)
-    out_ref[...] = _eq19_update(j, nbr_idx_ref, self_idx_ref, nbr_mask_ref,
-                                theta_ref, g_ref, d_ref, s_ref, p_ref)
+    out_ref[...] = _table_update(j, nbr_idx_ref, self_idx_ref, nbr_mask_ref,
+                                 theta_ref, g_ref, d_ref, s_ref, p_ref)[1]
 
 
 def _dekrr_step_masked_kernel(nbr_idx_ref, self_idx_ref, nbr_mask_ref,
@@ -135,38 +174,35 @@ def _dekrr_step_masked_kernel(nbr_idx_ref, self_idx_ref, nbr_mask_ref,
 
     @pl.when(is_active)
     def _update():
-        out_ref[...] = _eq19_update(j, nbr_idx_ref, self_idx_ref,
-                                    nbr_mask_ref, theta_ref, g_ref, d_ref,
-                                    s_ref, p_ref)
+        out_ref[...] = _table_update(j, nbr_idx_ref, self_idx_ref,
+                                     nbr_mask_ref, theta_ref, g_ref, d_ref,
+                                     s_ref, p_ref)[1]
 
     @pl.when(jnp.logical_not(is_active))
     def _passthrough():
         dy = d_ref.shape[0]
-        out_ref[...] = theta_ref[pl.ds(self_idx_ref[j] * dy, dy), :]
+        out_ref[...] = _rows(theta_ref, self_idx_ref[j] * dy, dy)
 
 
 def dekrr_step_pallas(g: jax.Array, d: jax.Array, s: jax.Array,
                       p: jax.Array, theta: jax.Array, nbr_idx: jax.Array,
                       self_idx: jax.Array, nbr_mask: jax.Array, *,
-                      active: jax.Array | None = None, dy: int = 1,
+                      active: jax.Array | None = None,
                       interpret: bool = False) -> jax.Array:
     """Raw pallas_call. All dims must already be padded/aligned:
 
-      g/s [J, D, D], d [J·Dy, D], p [J, K, D, D] with K ≥ 1 and D a
+      g/s [J, D, D], d [J, Dy, D], p [J, K, D, D] with K ≥ 1 and D a
       multiple of 128; theta [T·Dy, D] with T·Dy padded to a multiple of
       8; nbr_idx [J, K] int32 *table* rows (pre-flattening — the kernel
       scales by Dy); self_idx [J] int32; nbr_mask [J, K] int32.
     ``active`` ([J] int32, optional) selects the activation-masked async
     kernel: nodes with active[j] == 0 emit their own θ rows unchanged.
-    ``dy`` is the output width (1 = scalar targets, today's layout).
-    Returns the post-round θ rows, [J·Dy, D] (rows [r·Dy, (r+1)·Dy) for
-    node r — callers with T ≠ J re-assemble their table themselves).
+    Returns the post-round θ rows, [J, Dy, D] (callers with T ≠ J
+    re-assemble their table themselves).
     """
-    j_nodes = d.shape[0] // dy
-    d_feat = d.shape[1]
+    j_nodes, dy, d_feat = d.shape
     k_slots = p.shape[1]
     t_rows = theta.shape[0]
-    assert d.shape[0] % dy == 0, (d.shape, dy)
     assert d_feat % 128 == 0 and t_rows % 8 == 0, (d_feat, t_rows)
     assert k_slots >= 1, "pad the slot axis to K >= 1 (zero P blocks)"
 
@@ -181,18 +217,18 @@ def dekrr_step_pallas(g: jax.Array, d: jax.Array, s: jax.Array,
         in_specs=[
             pl.BlockSpec((t_rows, d_feat), lambda j, *_: (0, 0)),   # θ table
             pl.BlockSpec((1, d_feat, d_feat), lambda j, *_: (j, 0, 0)),
-            pl.BlockSpec((dy, d_feat), lambda j, *_: (j, 0)),
+            pl.BlockSpec((None, dy, d_feat), lambda j, *_: (j, 0, 0)),
             pl.BlockSpec((1, d_feat, d_feat), lambda j, *_: (j, 0, 0)),
             pl.BlockSpec((1, k_slots, d_feat, d_feat),
                          lambda j, *_: (j, 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((dy, d_feat), lambda j, *_: (j, 0)),
+        out_specs=pl.BlockSpec((None, dy, d_feat), lambda j, *_: (j, 0, 0)),
     )
     flops_per_node = 2 * (2 + k_slots) * d_feat * d_feat * dy
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((j_nodes * dy, d_feat), theta.dtype),
+        out_shape=jax.ShapeDtypeStruct((j_nodes, dy, d_feat), theta.dtype),
         cost_estimate=pl.CostEstimate(
             flops=j_nodes * flops_per_node,
             bytes_accessed=(t_rows * d_feat

@@ -59,16 +59,38 @@ def _flatten_dy(a: jax.Array) -> jax.Array:
     return a.transpose(0, 2, 1).reshape(t * dy, d_feat)
 
 
-def _unflatten_dy(out: jax.Array, dy: int, d_feat: int,
-                  ndim: int = 2) -> jax.Array:
-    """Invert `_flatten_dy` on a kernel output. Scalar-layout operands
-    (ndim == 2) take the exact old [:, :d_feat] slice; trailing-axis
-    operands (ndim == 3) restore [J, d_feat, dy] — even at dy == 1, so a
-    [.., 1] multi-output layout round-trips with its axis intact."""
+def _node_rows(a: jax.Array) -> jax.Array:
+    """[J, D] → [J, 1, D] and [J, D, Dy] → [J, Dy, D]: the kernels'
+    per-node row-block layout for d and the θ outputs (a leading node
+    axis, so each grid step's block is the array's whole trailing
+    [Dy, D] — a TPU-legal block at any Dy)."""
+    return a[:, None, :] if a.ndim == 2 else a.transpose(0, 2, 1)
+
+
+def _from_node_rows(out: jax.Array, d_feat: int,
+                    ndim: int = 2) -> jax.Array:
+    """Invert `_node_rows` on a kernel output [J, Dy, D_pad]. Scalar-layout
+    operands (ndim == 2) come back [J, d_feat]; trailing-axis operands
+    (ndim == 3) restore [J, d_feat, dy] — even at dy == 1, so a [.., 1]
+    multi-output layout round-trips with its axis intact."""
     if ndim == 2:
-        return out[:, :d_feat]
-    j_nodes = out.shape[0] // dy
-    return out.reshape(j_nodes, dy, -1)[:, :, :d_feat].transpose(0, 2, 1)
+        return out[:, 0, :d_feat]
+    return out[:, :, :d_feat].transpose(0, 2, 1)
+
+
+def _check_tpu_dtype(interpret: bool, *arrays) -> None:
+    """Refuse f64 operands for a compiled TPU kernel: the TPU has no f64,
+    and Mosaic would fail deep inside lowering. Callers cast at their
+    side (the chip path runs f32 with x64 off); interpret mode keeps
+    f64 for the CPU parity tests."""
+    if interpret:
+        return
+    for a in arrays:
+        if jnp.dtype(a.dtype) == jnp.float64:
+            raise ValueError(
+                f"Pallas TPU kernels take no float64 operands (got "
+                f"{a.dtype} {tuple(a.shape)}): cast to float32 before "
+                f"calling a compiled kernel, or pass interpret=True")
 
 
 def _check_dekrr_budget(kernel: str, d, p, theta) -> None:
@@ -141,6 +163,7 @@ def rff_gram(omega: jax.Array, bias: jax.Array, x: jax.Array, y: jax.Array,
     """
     if interpret is None:
         interpret = _interpret_default()
+    _check_tpu_dtype(interpret, omega, bias, x, y)
     d_feat, n = omega.shape[0], x.shape[1]
     dtype = x.dtype
 
@@ -175,6 +198,7 @@ def rff_features(omega: jax.Array, bias: jax.Array, x: jax.Array, *,
     """
     if interpret is None:
         interpret = _interpret_default()
+    _check_tpu_dtype(interpret, omega, bias, x)
     d_feat, n = omega.shape[0], x.shape[1]
     dtype = x.dtype
 
@@ -265,14 +289,14 @@ def _pad_dekrr_operands(g, d, s, p, theta, nbr_idx, nbr_mask):
     """Shared operand padding for the DeKRR round/solve kernels: D to lane
     multiples of 128, the θ table to sublane multiples of 8, the slot axis
     to K ≥ 1 (an all-masked zero-P slot for edgeless graphs), index/mask
-    tables coerced to int32. Multi-output d/theta ([.., D, Dy]) are first
-    flattened to the kernels' [rows·Dy, D] layout (identity at Dy = 1).
-    One helper so `dekrr_step` and `dekrr_solve` can never drift apart on
-    the operand layout."""
+    tables coerced to int32. d goes to the kernels' [J, Dy, D] node-row
+    layout and the θ table to the flat [T·Dy, D] one (identity at
+    Dy = 1). One helper so `dekrr_step` and `dekrr_solve` can never
+    drift apart on the operand layout."""
     j_nodes = d.shape[0]
     g_p = _pad_to(_pad_to(g, 1, 128), 2, 128)
     s_p = _pad_to(_pad_to(s, 1, 128), 2, 128)
-    d_p = _pad_to(_flatten_dy(d), 1, 128)
+    d_p = _pad_to(_node_rows(d), 2, 128)
     p_p = _pad_to(_pad_to(p, 2, 128), 3, 128)
     if p_p.shape[1] == 0:                       # K = 0 (edgeless graph)
         p_p = jnp.zeros((j_nodes, 1) + p_p.shape[2:], p_p.dtype)
@@ -288,8 +312,8 @@ def _dekrr_step_jit(g, d, s, p, theta, nbr_idx, self_idx, nbr_mask,
                     active=None, *, interpret=None):
     if interpret is None:
         interpret = _interpret_default()
+    _check_tpu_dtype(interpret, g, d, s, p, theta)
     d_feat = d.shape[1]
-    dy = _dekrr_dy(d)
 
     g_p, d_p, s_p, p_p, theta_p, nbr_idx_p, nbr_mask_p = \
         _pad_dekrr_operands(g, d, s, p, theta, nbr_idx, nbr_mask)
@@ -297,8 +321,8 @@ def _dekrr_step_jit(g, d, s, p, theta, nbr_idx, self_idx, nbr_mask,
     out = dekrr_step_pallas(
         g_p, d_p, s_p, p_p, theta_p,
         nbr_idx_p, self_idx.astype(jnp.int32), nbr_mask_p,
-        active=active_p, dy=dy, interpret=interpret)
-    return _unflatten_dy(out, dy, d_feat, d.ndim)
+        active=active_p, interpret=interpret)
+    return _from_node_rows(out, d_feat, d.ndim)
 
 
 def dekrr_step(g: jax.Array, d: jax.Array, s: jax.Array, p: jax.Array,
@@ -343,23 +367,23 @@ def _dekrr_solve_jit(g, d, s, p, theta, nbr_idx, self_idx, nbr_mask, *,
     if interpret is None:
         interpret = _interpret_default()
     d_feat = d.shape[1]
-    dy = _dekrr_dy(d)
     self_idx = self_idx.astype(jnp.int32)
     if num_rounds == 0:
         out0 = theta[self_idx]
         if trace:
             return out0, jnp.zeros((0, d.shape[0]), theta.dtype)
         return out0
+    _check_tpu_dtype(interpret, g, d, s, p, theta)
 
     g_p, d_p, s_p, p_p, theta_p, nbr_idx_p, nbr_mask_p = \
         _pad_dekrr_operands(g, d, s, p, theta, nbr_idx, nbr_mask)
     out = dekrr_solve_pallas(
         g_p, d_p, s_p, p_p, theta_p, nbr_idx_p, self_idx, nbr_mask_p,
-        num_rounds=num_rounds, dy=dy, trace=trace, interpret=interpret)
+        num_rounds=num_rounds, trace=trace, interpret=interpret)
     if trace:
         out, res = out
-        return _unflatten_dy(out, dy, d_feat, d.ndim), res
-    return _unflatten_dy(out, dy, d_feat, d.ndim)
+        return _from_node_rows(out, d_feat, d.ndim), res[:, 0]
+    return _from_node_rows(out, d_feat, d.ndim)
 
 
 def dekrr_solve(g: jax.Array, d: jax.Array, s: jax.Array, p: jax.Array,
@@ -428,6 +452,8 @@ def _dekrr_async_solve_jit(g, d, s, p, theta, sent, buffers, nbr_idx,
                            censored, trace=False, interpret=None):
     if interpret is None:
         interpret = _interpret_default()
+    _check_tpu_dtype(interpret, g, d, s, p, theta, sent, buffers,
+                     thresholds)
     j_nodes, d_feat = d.shape[0], d.shape[1]
     dy = _dekrr_dy(d)
     k_in = buffers.shape[1]
@@ -451,20 +477,20 @@ def _dekrr_async_solve_jit(g, d, s, p, theta, sent, buffers, nbr_idx,
     outs = dekrr_async_solve_pallas(
         g_p, d_p, s_p, p_p, theta_p, sent_p, buf_p, nbr_idx_p, nbr_mask_p,
         (active_tab != 0).astype(jnp.int32), thresholds.astype(d.dtype),
-        censored=censored, edge_gossip=(gossip == "edge"), dy=dy,
+        censored=censored, edge_gossip=(gossip == "edge"),
         trace=trace, interpret=interpret)
     out_theta, out_sent, out_buf = outs[:3]
+    out_buf = out_buf.reshape(j_nodes, k_pad, dy, -1)[:, :k_in, :, :d_feat]
     if d.ndim == 2:
-        out_buf = out_buf.reshape(j_nodes, k_pad, -1)[:, :k_in, :d_feat]
+        out_buf = out_buf[:, :, 0]
     else:
-        out_buf = out_buf.reshape(j_nodes, k_pad, dy, -1)[
-            :, :k_in, :, :d_feat].transpose(0, 1, 3, 2)
-    state = (_unflatten_dy(out_theta, dy, d_feat, d.ndim),
-             _unflatten_dy(out_sent, dy, d_feat, d.ndim), out_buf)
+        out_buf = out_buf.transpose(0, 1, 3, 2)
+    state = (_from_node_rows(out_theta, d_feat, d.ndim),
+             _from_node_rows(out_sent, d_feat, d.ndim), out_buf)
     if trace:
         # Drop the delivery-flush row — it computes no round.
         res, bc = outs[3], outs[4]
-        return state + (res[:num_rounds], bc[:num_rounds])
+        return state + (res[:num_rounds, 0], bc[:num_rounds, 0])
     return state
 
 
@@ -535,8 +561,8 @@ def _dekrr_cheb_solve_jit(g, d, s, p, theta, delta, nbr_idx, self_idx,
                           interpret=None):
     if interpret is None:
         interpret = _interpret_default()
+    _check_tpu_dtype(interpret, g, d, s, p, theta, delta, alphas, betas)
     d_feat = d.shape[1]
-    dy = _dekrr_dy(d)
 
     g_p, d_p, s_p, p_p, theta_p, nbr_idx_p, nbr_mask_p = \
         _pad_dekrr_operands(g, d, s, p, theta, nbr_idx, nbr_mask)
@@ -544,12 +570,12 @@ def _dekrr_cheb_solve_jit(g, d, s, p, theta, delta, nbr_idx, self_idx,
     outs = dekrr_cheb_solve_pallas(
         g_p, d_p, s_p, p_p, theta_p, delta_p, nbr_idx_p,
         self_idx.astype(jnp.int32), nbr_mask_p,
-        alphas.astype(d.dtype), betas.astype(d.dtype), dy=dy,
+        alphas.astype(d.dtype), betas.astype(d.dtype),
         trace=trace, interpret=interpret)
-    out = (_unflatten_dy(outs[0], dy, d_feat, d.ndim),
-           _unflatten_dy(outs[1], dy, d_feat, d.ndim))
+    out = (_from_node_rows(outs[0], d_feat, d.ndim),
+           _from_node_rows(outs[1], d_feat, d.ndim))
     if trace:
-        return out + (outs[2],)
+        return out + (outs[2][:, 0],)
     return out
 
 
@@ -614,6 +640,7 @@ def rff_gram_batched(omega: jax.Array, bias: jax.Array, x: jax.Array,
     """
     if interpret is None:
         interpret = _interpret_default()
+    _check_tpu_dtype(interpret, omega, bias, x, y)
     f_feat, n = omega.shape[1], x.shape[2]
 
     bn = min(block_n, max(128, 1 << (n - 1).bit_length()))

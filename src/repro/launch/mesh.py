@@ -23,14 +23,10 @@ def make_production_mesh(*, multi_pod: bool = False,
 
 
 def make_abstract_mesh(shape, axes):
-    """Device-free AbstractMesh across jax versions: 0.4.x takes a tuple of
-    (name, size) pairs, newer jax takes (axis_sizes, axis_names)."""
+    """Device-free AbstractMesh of the given axis sizes and names."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def make_cpu_mesh(num_devices: int | None = None, axis: str = "nodes"):

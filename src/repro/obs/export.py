@@ -51,32 +51,27 @@ _PROM_NAME = re.compile(r"[^a-zA-Z0-9_:]")
 def provenance(*, interpret: bool | None = None,
                extra: dict[str, Any] | None = None) -> dict[str, Any]:
     """Run-provenance block: git sha, jax version, device kind,
-    platform, interpret-mode flag. Every probe is best-effort — a
-    missing git checkout or an unimportable jax degrades to None, never
-    raises (benches must stamp their artifacts even on odd hosts)."""
+    platform, device count, interpret-mode flag. The git probe is
+    best-effort (a copy without a checkout stamps None); the device is
+    read from jax and a failure to read it raises — an artifact must
+    never claim an unknown device."""
     sha = None
     try:
         sha = subprocess.run(
             ["git", "rev-parse", "HEAD"], capture_output=True, text=True,
             timeout=10, cwd=os.path.dirname(os.path.abspath(__file__)),
         ).stdout.strip() or None
-    except Exception:
+    except (OSError, subprocess.SubprocessError):
         sha = None
-    jax_version = device_kind = platform = None
-    try:
-        import jax
+    import jax
 
-        jax_version = jax.__version__
-        dev = jax.devices()[0]
-        device_kind = dev.device_kind
-        platform = dev.platform
-    except Exception:
-        pass
+    devices = jax.devices()
     block = {
         "git_sha": sha,
-        "jax_version": jax_version,
-        "device_kind": device_kind,
-        "platform": platform,
+        "jax_version": jax.__version__,
+        "device_kind": devices[0].device_kind,
+        "platform": devices[0].platform,
+        "device_count": len(devices),
         "interpret": interpret,
         "t_wall": float(wall_clock()),
     }

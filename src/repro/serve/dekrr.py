@@ -324,7 +324,9 @@ def answer_wave(st: _StagedSnapshot,
     traces it for the J002 dispatch pins.
     """
     if st.precision is None:
-        preds = [t2.T @ _features_hi(fm, x, st.backend)
+        # HIGHEST: a TPU's default f32 matmul would round θ to bf16
+        preds = [jnp.matmul(t2.T, _features_hi(fm, x, st.backend),
+                            precision=jax.lax.Precision.HIGHEST)
                  for fm, t2 in zip(st.snap.feature_maps, st.theta2)]
         return jnp.stack(preds), None
 

@@ -312,7 +312,7 @@ SPMD_ANALYSIS_SCRIPT = textwrap.dedent("""
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec
     from repro.analysis import jaxpr_lint as JL
-    from repro.dist.dekrr_spmd import shard_map
+    from jax import shard_map
 
     # live repo: all entry points (incl. SPMD ppermute/allgather) clean
     findings = JL.run_pass()
@@ -326,7 +326,7 @@ SPMD_ANALYSIS_SCRIPT = textwrap.dedent("""
         def prog(x):
             return lax.ppermute(x, "nodes", [(0, 1), (1, 2)])
         return shard_map(prog, mesh=mesh, in_specs=P("nodes"),
-                         out_specs=P("nodes"), check_rep=False)(x)
+                         out_specs=P("nodes"), check_vma=False)(x)
     cj = jax.make_jaxpr(bad_perm)(jnp.zeros((4, 2)))
     rules = [f.rule for f in JL.lint_program(cj, "seed")]
     assert "J003" in rules, rules
@@ -343,7 +343,7 @@ SPMD_ANALYSIS_SCRIPT = textwrap.dedent("""
                         c[1] + 1)
             return lax.while_loop(cond, body, (x, 0))[0]
         return shard_map(prog, mesh=mesh, in_specs=P("nodes"),
-                         out_specs=P("nodes"), check_rep=False)(x)
+                         out_specs=P("nodes"), check_vma=False)(x)
     cj = jax.make_jaxpr(unreplicated_loop)(jnp.zeros((4, 2)))
     rules = [f.rule for f in JL.lint_program(cj, "seed")]
     assert "J005" in rules, rules
@@ -359,7 +359,7 @@ SPMD_ANALYSIS_SCRIPT = textwrap.dedent("""
                         + d * 0, c[1] + 1)
             return lax.while_loop(cond, body, (x, 0))[0]
         return shard_map(prog, mesh=mesh, in_specs=P("nodes"),
-                         out_specs=P("nodes"), check_rep=False)(x)
+                         out_specs=P("nodes"), check_vma=False)(x)
     cj = jax.make_jaxpr(replicated_loop)(jnp.zeros((4, 2)))
     rules = [f.rule for f in JL.lint_program(cj, "seed")]
     assert "J005" not in rules, rules
@@ -374,6 +374,36 @@ def test_spmd_lint_and_replication_seeds():
         cwd=REPO_ROOT, env=subprocess_env())
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "SPMD-ANALYSIS-OK" in proc.stdout
+
+
+J005_COVERAGE_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax
+    jax.config.update("jax_enable_x64", True)
+    from repro.analysis import jaxpr_lint as JL
+
+    counts = {ep.label: len(JL.unverified_shard_maps(ep.trace()))
+              for ep in JL.spmd_entry_points()}
+    # The Pallas and tol>0 SPMD paths turn JAX's own check off, so J005
+    # is their only replication check; it must actually see them.
+    unchecked = [k for k in counts if "pallas" in k or "tol>0" in k]
+    assert unchecked and all(counts[k] == 1 for k in unchecked), counts
+    assert sum(counts.values()) == len(unchecked), counts
+    print("J005-COVERAGE-OK", sum(counts.values()))
+""")
+
+
+def test_replication_pass_inspects_live_shard_maps():
+    """J005 analyzes only shard_maps traced with check_vma=False; a JAX
+    upgrade that renames that parameter would silently leave it nothing
+    to inspect, so the live SPMD programs must each give it one."""
+    proc = subprocess.run(
+        [sys.executable, "-c", J005_COVERAGE_SCRIPT],
+        capture_output=True, text=True, timeout=600,
+        cwd=REPO_ROOT, env=subprocess_env())
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert "J005-COVERAGE-OK" in proc.stdout
 
 
 # ---------------------------------------------------------------------------

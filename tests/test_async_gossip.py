@@ -217,6 +217,36 @@ def test_fused_async_chain_conformance(gossip, censored):
                                       err_msg=f"chunk_rounds={chunk}")
 
 
+def test_fused_async_chain_splits_long_schedules(monkeypatch):
+    """The fused chain prefetches its [R, J] activation table into SMEM
+    (1 MiB on a v5e core, rows padded to 128 lanes), so a schedule longer
+    than `_max_fused_rounds` runs in several dispatches — bit-identical
+    to one, whatever chunk_rounds asked for."""
+    from repro.core import activation_masks, censor_schedule
+    from repro.dist import async_gossip as ag
+    from repro.obs.dispatch import dispatch_count
+
+    _, packed, _ = _problem("circulant")
+    config = AsyncGossipConfig(prob=0.6, **CENSOR)
+    rounds = 11
+    whole = async_solve_batched(packed, rounds, KEY, config=config,
+                                backend="pallas_fused")
+    monkeypatch.setattr(ag, "_SMEM_TABLE_BYTES", 4 * 128 * 4)
+    assert ag._max_fused_rounds(packed.num_nodes) == 4
+    masks = activation_masks(KEY, rounds, packed.num_nodes, prob=config.prob)
+    thresholds = censor_schedule(config.censor_tau, config.censor_decay,
+                                 rounds, dtype=packed.d.dtype)
+
+    def fused(pk):
+        return ag._async_solve_fused(
+            pk, ag.init_async_state(pk), masks, thresholds,
+            gossip=config.gossip, censored=True, chunk_rounds=64)
+
+    assert dispatch_count(fused, packed) == (3, True)     # 4 + 4 + 3
+    np.testing.assert_array_equal(np.asarray(fused(packed)),
+                                  np.asarray(whole))
+
+
 def test_fused_async_stats_fall_back_to_per_round():
     """return_stats=True keeps the per-round accounting path even under
     backend="pallas_fused" — its θ and wire counts must match XLA's."""

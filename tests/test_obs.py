@@ -613,6 +613,24 @@ def test_jsonl_and_prometheus_exports(tmp_path):
     assert "span" not in prom               # traces are JSONL-only
 
 
+def test_provenance_names_the_device_or_raises(monkeypatch):
+    """The device block comes from jax; a failure to read it is raised,
+    never stamped as an unknown device."""
+    import jax
+
+    prov = obs_export.provenance()
+    assert prov["platform"] == jax.devices()[0].platform
+    assert prov["device_kind"] == jax.devices()[0].device_kind
+    assert prov["device_count"] == len(jax.devices())
+
+    def no_backend():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", no_backend)
+    with pytest.raises(RuntimeError, match="initialize backend"):
+        obs_export.provenance()
+
+
 def test_stamp_provenance(tmp_path):
     prov = {"git_sha": "abc", "t_wall": 0.0}
     d = tmp_path / "BENCH_dict.json"
