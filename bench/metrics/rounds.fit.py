@@ -1,0 +1,8 @@
+"""Mean rounds to tol per fit, as `solve_batched(return_rounds=True)`
+counts them on the device."""
+import math
+
+
+def read(view):
+    r = view.result["counts"]["rounds_mean"]
+    return None if math.isnan(r) else r
