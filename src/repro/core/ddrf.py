@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.rff import FeatureMap, featurize, sample_rff
+from repro.obs.spans import count, h2d_nbytes, is_recording, span
 
 
 def _fold_paired(per_feature: jax.Array, fmap: FeatureMap) -> jax.Array:
@@ -108,23 +109,26 @@ def select_features(
         return sample_rff(key, dim, num_features, sigma, kind=kind)
 
     d0 = candidate_ratio * num_features
-    k_cand, k_res = jax.random.split(key)
-    cand = sample_rff(k_cand, dim, d0, sigma, kind=kind)
+    with span("ddrf.select", d0=d0, D=num_features, N=int(x.shape[-1])):
+        if is_recording():      # numpy x and y are copied to the device
+            count("ddrf.h2d_bytes", h2d_nbytes(x, y))
+        k_cand, k_res = jax.random.split(key)
+        cand = sample_rff(k_cand, dim, d0, sigma, kind=kind)
 
-    if method == "energy":
-        if y is None:
-            raise ValueError("energy scoring requires labels y")
-        scores = energy_scores(cand, x, y)
-        idx = jnp.argsort(-scores)[:num_features]
-    elif method == "leverage":
-        scores = leverage_scores(cand, x, lam=leverage_lam)
-        idx = jnp.argsort(-scores)[:num_features]
-    elif method == "leverage_resample":
-        scores = leverage_scores(cand, x, lam=leverage_lam)
-        p = jnp.maximum(scores, 0.0)
-        p = p / jnp.sum(p)
-        idx = jax.random.choice(k_res, d0, shape=(num_features,),
-                                replace=False, p=p)
-    else:
-        raise ValueError(f"unknown DDRF method {method!r}")
-    return cand.subset(idx)
+        if method == "energy":
+            if y is None:
+                raise ValueError("energy scoring requires labels y")
+            scores = energy_scores(cand, x, y)
+            idx = jnp.argsort(-scores)[:num_features]
+        elif method == "leverage":
+            scores = leverage_scores(cand, x, lam=leverage_lam)
+            idx = jnp.argsort(-scores)[:num_features]
+        elif method == "leverage_resample":
+            scores = leverage_scores(cand, x, lam=leverage_lam)
+            p = jnp.maximum(scores, 0.0)
+            p = p / jnp.sum(p)
+            idx = jax.random.choice(k_res, d0, shape=(num_features,),
+                                    replace=False, p=p)
+        else:
+            raise ValueError(f"unknown DDRF method {method!r}")
+        return cand.subset(idx)

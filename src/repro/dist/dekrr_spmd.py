@@ -100,7 +100,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.analysis.vmem import check_index_table
-from repro.obs.spans import span
+from repro.obs.spans import count, h2d_nbytes, is_recording, span
 from repro.obs.trace import SolveTrace
 
 __all__ = [
@@ -413,6 +413,14 @@ def _stage_feature_maps(fmaps, dtype) -> dict:
                 node_dims=tuple(int(v) for v in dims))
 
 
+def _to_device(*arrays) -> list:
+    """`jnp.asarray` of each array, the numpy bytes copied counted as
+    `pack.h2d_bytes` while recording."""
+    if is_recording():
+        count("pack.h2d_bytes", h2d_nbytes(*arrays))
+    return [jnp.asarray(a) for a in arrays]
+
+
 def _stage_packed_inputs(solver, *, gram_backend: str | None) -> dict:
     """Numpy-stage padded [J, …] inputs for the batched Eq. 17 build.
 
@@ -423,52 +431,54 @@ def _stage_packed_inputs(solver, *, gram_backend: str | None) -> dict:
     """
     if gram_backend is None:
         gram_backend = "pallas" if jax.default_backend() == "tpu" else "xla"
-    j_nodes = solver.J
-    dtype = np.asarray(solver.data[0].x).dtype
+    with span("pack.stage"):
+        j_nodes = solver.J
+        dtype = np.asarray(solver.data[0].x).dtype
 
-    maps = _stage_feature_maps(solver.feature_maps, dtype)
-    kind = maps["kind"]
-    omega, bias = maps["omega"], maps["bias"]
-    feat_idx, feat_mask = maps["feat_idx"], maps["feat_mask"]
-    scale = maps["scale"]
-    sizes = np.array([nd.num_samples for nd in solver.data])
-    n_max = int(sizes.max())
-    dim_in = solver.data[0].x.shape[0]
+        maps = _stage_feature_maps(solver.feature_maps, dtype)
+        kind = maps["kind"]
+        omega, bias = maps["omega"], maps["bias"]
+        feat_idx, feat_mask = maps["feat_idx"], maps["feat_mask"]
+        scale = maps["scale"]
+        sizes = np.array([nd.num_samples for nd in solver.data])
+        n_max = int(sizes.max())
+        dim_in = solver.data[0].x.shape[0]
 
-    x = np.zeros((j_nodes, dim_in, n_max), dtype=dtype)
-    dy = solver.data[0].num_outputs if solver.data[0].y.ndim > 1 else None
-    y = np.zeros((j_nodes, n_max) if dy is None else (j_nodes, n_max, dy),
-                 dtype=dtype)
-    for j, nd in enumerate(solver.data):
-        x[j, :, :sizes[j]] = np.asarray(nd.x)
-        if dy is None:
-            y[j, :sizes[j]] = np.asarray(nd.y).reshape(-1)
-        else:
-            y[j, :sizes[j]] = np.asarray(nd.y)
-    col_mask = (np.arange(n_max)[None, :] < sizes[:, None]).astype(dtype)
+        x = np.zeros((j_nodes, dim_in, n_max), dtype=dtype)
+        dy = solver.data[0].num_outputs if solver.data[0].y.ndim > 1 else None
+        y = np.zeros((j_nodes, n_max) if dy is None else (j_nodes, n_max, dy),
+                     dtype=dtype)
+        for j, nd in enumerate(solver.data):
+            x[j, :, :sizes[j]] = np.asarray(nd.x)
+            if dy is None:
+                y[j, :sizes[j]] = np.asarray(nd.y).reshape(-1)
+            else:
+                y[j, :sizes[j]] = np.asarray(nd.y)
+        col_mask = (np.arange(n_max)[None, :] < sizes[:, None]).astype(dtype)
 
-    ct_self, ct_nei = solver.coupling_coefficients()
-    degs = solver.topology.degrees.astype(dtype)
-    nbr_idx, nbr_mask, offsets = _slot_table(solver)
+        ct_self, ct_nei = solver.coupling_coefficients()
+        degs = solver.topology.degrees.astype(dtype)
+        nbr_idx, nbr_mask, offsets = _slot_table(solver)
 
-    gather = lambda a: a[nbr_idx]            # [J, K, …] by slot table
-    staged = dict(
-        omega=omega, bias=bias, x=x, y=y,
-        col_mask=col_mask, feat_mask=feat_mask, feat_idx=feat_idx,
-        scale=scale,
-        omega_n=gather(omega), bias_n=gather(bias), x_n=gather(x),
-        col_mask_n=gather(col_mask), feat_mask_n=gather(feat_mask),
-        feat_idx_n=gather(feat_idx), scale_n=gather(scale),
-        ct_self=ct_self.astype(dtype), ct_nei=ct_nei.astype(dtype),
-        ct_nei_n=gather(ct_nei.astype(dtype)),
-        degree=degs, nbr_mask=nbr_mask.astype(dtype),
-        lam_over_j=np.full((j_nodes,),
-                           solver.config.lam / solver.J, dtype=dtype),
-        n_total=np.full((j_nodes,), float(solver.N), dtype=dtype),
-        kind=kind,
-    )
+        gather = lambda a: a[nbr_idx]            # [J, K, …] by slot table
+        staged = dict(
+            omega=omega, bias=bias, x=x, y=y,
+            col_mask=col_mask, feat_mask=feat_mask, feat_idx=feat_idx,
+            scale=scale,
+            omega_n=gather(omega), bias_n=gather(bias), x_n=gather(x),
+            col_mask_n=gather(col_mask), feat_mask_n=gather(feat_mask),
+            feat_idx_n=gather(feat_idx), scale_n=gather(scale),
+            ct_self=ct_self.astype(dtype), ct_nei=ct_nei.astype(dtype),
+            ct_nei_n=gather(ct_nei.astype(dtype)),
+            degree=degs, nbr_mask=nbr_mask.astype(dtype),
+            lam_over_j=np.full((j_nodes,),
+                               solver.config.lam / solver.J, dtype=dtype),
+            n_total=np.full((j_nodes,), float(solver.N), dtype=dtype),
+            kind=kind,
+        )
     if gram_backend == "pallas" and kind == "cos_bias" and j_nodes > 0:
-        staged.update(_pallas_gram_blocks(staged))
+        with span("pack.gram"):
+            staged.update(_pallas_gram_blocks(staged))
     # bookkeeping for _finish_packed (not builder inputs)
     staged["_meta"] = (maps["node_dims"], nbr_idx, offsets)
     return staged
@@ -490,9 +500,7 @@ def _pallas_gram_blocks(staged: dict) -> dict:
     # `_node_aux` from the packed features instead, and the kernel only
     # supplies the Gram blocks.
     y_kernel = y if y.ndim == 2 else np.zeros(y.shape[:2], x.dtype)
-    graw, zyraw = rff_gram_batched(
-        jnp.asarray(omega), jnp.asarray(bias), jnp.asarray(x),
-        jnp.asarray(y_kernel), jnp.asarray(cm))
+    graw, zyraw = rff_gram_batched(*_to_device(omega, bias, x, y_kernel, cm))
     f_max, dim_in = omega.shape[1:]
     if k_slots == 0:
         gcross = np.zeros((j_nodes, 0, f_max, f_max), x.dtype)
@@ -505,9 +513,9 @@ def _pallas_gram_blocks(staged: dict) -> dict:
                              (j_nodes, k_slots, f_max)).reshape(-1, f_max)
     x_n = staged["x_n"].reshape((-1,) + x.shape[1:])
     cm_n = staged["col_mask_n"].reshape(-1, cm.shape[1])
-    gcross, _ = rff_gram_batched(
-        jnp.asarray(om_rep), jnp.asarray(bi_rep), jnp.asarray(x_n),
-        jnp.zeros(cm_n.shape, x.dtype), jnp.asarray(cm_n))
+    om_rep, bi_rep, x_n, cm_n = _to_device(om_rep, bi_rep, x_n, cm_n)
+    gcross, _ = rff_gram_batched(om_rep, bi_rep, x_n,
+                                 jnp.zeros(cm_n.shape, x.dtype), cm_n)
     return dict(
         gram_raw=np.asarray(graw), zy_raw=np.asarray(zyraw),
         gram_cross_raw=np.asarray(gcross).reshape(
@@ -624,19 +632,19 @@ def _vmapped_node_aux(kind, **arrays):
 def _build_packed_aux(*, kind, _meta=None, **staged):
     """One traced program for the whole network (trace count independent of
     J) — see `_vmapped_node_aux` for the counter the regression test pins."""
-    return _vmapped_node_aux(kind=kind, **{k: jnp.asarray(v)
-                                           for k, v in staged.items()})
+    return _vmapped_node_aux(kind=kind, **dict(zip(
+        staged, _to_device(*staged.values()))))
 
 
 def _finish_packed(staged: dict, built) -> PackedProblem:
     g, d, s, p = built
     dims, nbr_idx, offsets = staged["_meta"]
     num_edges = _validate_slot_table(nbr_idx, staged["nbr_mask"], len(dims))
+    theta_mask, nbr_idx_dev, nbr_mask = _to_device(
+        staged["feat_mask"], nbr_idx, staged["nbr_mask"])
     return PackedProblem(
         g=g, d=d, s=s, p=p,
-        theta_mask=jnp.asarray(staged["feat_mask"]),
-        nbr_idx=jnp.asarray(nbr_idx),
-        nbr_mask=jnp.asarray(staged["nbr_mask"]),
+        theta_mask=theta_mask, nbr_idx=nbr_idx_dev, nbr_mask=nbr_mask,
         offsets=offsets, node_dims=dims, num_edges_directed=num_edges,
     )
 

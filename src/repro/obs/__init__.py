@@ -15,10 +15,17 @@ Architecture map
             exact vs per-round recomputation (tests/test_obs.py).
 
     HOST SIDE (stdlib clocks, injectable — R006 chokepoint)
-      pack_problem · stream ingest/refresh/publish · serve waves ·
-      bench suites
+      pack_problem (pack.stage, pack.gram) · ddrf.select · stream
+      ingest/refresh/publish · serve waves
         └─▶ spans (repro.obs.spans: nested context-manager intervals,
-            recorded only while a SpanRecorder is installed)
+            recorded only while a SpanRecorder is installed, and then
+            also jax.profiler.TraceAnnotations named TIMELINE_PREFIX +
+            name, on the device trace's clock)
+      ddrf.h2d_bytes · pack.h2d_bytes (numpy bytes copied to the device)
+      · jax.compiles (executables compiled or loaded, under recording())
+        └─▶ counts (repro.obs.spans.count: SpanRecorder.counts plus a
+            COUNT_PREFIX annotation on the timeline, no-op without a
+            recorder; byte sums only while is_recording())
       counters / gauges / histograms / LatencyRecorder
         └─▶ Registry (repro.obs.metrics: one named home per run;
             LatencyRecorder/LatencyReport live here — repro.serve
@@ -34,7 +41,6 @@ Architecture map
                    provenance block) ──▶ `python -m repro.obs` report
                    (convergence table, comm frontier, span waterfall,
                    serve percentiles)
-               ──▶ Prometheus text exposition (metrics only)
       provenance() / stamp_provenance() — git sha, jax version, device
       kind, interpret flag stamped into every BENCH_*.json by
       benchmarks/run.py.
@@ -46,14 +52,15 @@ waterfalls and percentiles). The exporters carry both, tagged by kind.
 
 Importing `repro.obs` (and `.metrics`/`.trace`/`.spans`/`.export`) does
 NOT import jax — the analysis CLI configures the jax platform first and
-times itself with obs clocks. Only `dispatch_count` touches jax, lazily.
+times itself with obs clocks. Only `dispatch_count` and an installed
+recorder touch jax, lazily.
 """
 from repro.obs import export, spans
 from repro.obs.dispatch import count_pallas_dispatches, dispatch_count
 from repro.obs.metrics import (Counter, FakeClock, Gauge, Histogram,
                                LatencyRecorder, LatencyReport, Registry,
                                perf_clock, wall_clock)
-from repro.obs.spans import Span, SpanRecorder, recording, span
+from repro.obs.spans import Span, SpanRecorder, count, recording, span
 from repro.obs.trace import AsyncSolveTrace, SolveTrace
 
 __all__ = [
@@ -68,6 +75,7 @@ __all__ = [
     "SolveTrace",
     "Span",
     "SpanRecorder",
+    "count",
     "count_pallas_dispatches",
     "dispatch_count",
     "export",

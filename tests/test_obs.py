@@ -549,6 +549,159 @@ def test_instrumented_pack_problem_emits_span():
     assert sp.attrs["nodes"] == 1
 
 
+def _tiny_ring_solver():
+    """A 4-node ring at a tiny size, cos_bias DDRF maps (Pallas Gram ok)."""
+    ds, train, _ = cached_split("air_quality", 4, subsample=200, seed=0)
+    fmaps = cached_fmaps("air_quality", 4, (6, 8, 6, 8), subsample=200,
+                         seed=0)
+    return ds, train, DeKRRSolver(circulant(4, (1,)), fmaps, train,
+                                  DeKRRConfig(lam=1e-6), build_aux=False)
+
+
+def _timeline_events(trace_dir):
+    """[(name, start_ns, end_ns, stats)] of the program's host events in
+    the newest profiler trace under `trace_dir`."""
+    import glob
+
+    path = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))[-1]
+    data = jax.profiler.ProfileData.from_file(path)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith(obs_spans.TIMELINE_PREFIX)]
+
+
+def test_timeline_spans_nest_on_profiler_trace(tmp_path):
+    """A recorder puts pack_problem's spans on the profiler's host plane:
+    pack.stage, then pack.gram, both inside pack_problem; and each count
+    as a marker inside the span that counted it, summing to the
+    recorder's count."""
+    _, _, solver = _tiny_ring_solver()
+    pack_problem(solver, gram_backend="pallas")         # compile outside
+    with jax.profiler.trace(str(tmp_path)):
+        with obs_spans.recording() as rec:
+            pack_problem(solver, gram_backend="pallas")
+    assert [(sp.name, sp.parent) for sp in rec.spans] == [
+        ("pack.stage", "pack_problem"), ("pack.gram", "pack_problem"),
+        ("pack_problem", None)]
+    pre, cpre = obs_spans.TIMELINE_PREFIX, obs_spans.COUNT_PREFIX
+    timeline = _timeline_events(tmp_path)
+    events = {n[len(pre):]: (s, e, st) for n, s, e, st in timeline
+              if not n.startswith(cpre)}
+    assert set(events) == {"pack_problem", "pack.stage", "pack.gram"}
+    p0, p1, attrs = events["pack_problem"]
+    s0, s1, _ = events["pack.stage"]
+    g0, g1, _ = events["pack.gram"]
+    assert p0 <= s0 < s1 <= g0 < g1 <= p1
+    assert attrs == {"nodes": 4, "method": "batched"}
+    marks = [(n[len(cpre):], s, st["n"]) for n, s, _, st in timeline
+             if n.startswith(cpre)]
+    assert {n for n, _, _ in marks} <= {"pack.h2d_bytes", obs_spans.COMPILES}
+    h2d = [(s, v) for n, s, v in marks if n == "pack.h2d_bytes"]
+    assert len(h2d) == 4        # Gram pass ×2, then the build and finish
+    assert all(g0 <= s < g1 for s, _ in h2d[:2])
+    assert all(g1 <= s < p1 for s, _ in h2d[2:])
+    assert sum(v for _, v in h2d) == rec.counts["pack.h2d_bytes"]
+
+
+def test_counts_and_timeline_do_nothing_without_recorder(monkeypatch):
+    """Outside a recording no annotation is entered, no byte sum is
+    computed and no listener stays registered; count() is a no-op."""
+    import repro.core.ddrf as ddrf_mod
+    import repro.dist.dekrr_spmd as spmd_mod
+    from repro.core import select_features
+
+    from jax._src import monitoring as jax_monitoring
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("ran outside a recording")
+
+    registered = jax_monitoring.get_event_duration_listeners
+    listeners = len(registered())
+    with obs_spans.recording():
+        assert len(registered()) == listeners + 1
+    assert len(registered()) == listeners
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    monkeypatch.setattr(ddrf_mod, "h2d_nbytes", refuse)
+    monkeypatch.setattr(spmd_mod, "h2d_nbytes", refuse)
+    ds, train, solver = _tiny_ring_solver()
+    obs_spans.count("orphan", 3)
+    assert not obs_spans.is_recording()
+    select_features(KEY, ds.dim, 6, 1.0, np.asarray(train[0].x),
+                    np.asarray(train[0].y), candidate_ratio=5)
+    pack_problem(solver)
+    assert len(registered()) == listeners
+    monkeypatch.undo()
+    with obs_spans.recording() as rec:
+        with obs_spans.span("recorded"):
+            obs_spans.count("n", 2)
+    assert rec.counts == {obs_spans.COMPILES: 0, "n": 2}
+    assert rec.spans[0].name == "recorded"
+
+
+def test_compiles_count_a_forced_recompile():
+    f = jax.jit(lambda a: a * 2.0 + 1.0)
+    a3, a5 = np.ones(3, np.float32), np.ones(5, np.float32)
+    with obs_spans.recording() as rec:
+        assert rec.counts == {obs_spans.COMPILES: 0}
+        f(a3)
+        seen = [rec.counts[obs_spans.COMPILES]]
+        f(a3)
+        seen.append(rec.counts[obs_spans.COMPILES])
+        f(a5)                                   # new shape: recompiles
+        seen.append(rec.counts[obs_spans.COMPILES])
+    assert seen == [1, 1, 2]
+    f(a3.reshape(1, 3))                         # after the recording
+    assert rec.counts[obs_spans.COMPILES] == 2
+
+
+@pytest.mark.parametrize("gram_backend", ["xla", "pallas"])
+def test_pack_h2d_bytes_equal_staged_nbytes(gram_backend):
+    """pack.h2d_bytes is every numpy byte the batched pack uploads: the
+    Gram pass's inputs (Pallas only), the staged builder inputs, and the
+    finished problem's masks and slot table; ddrf.h2d_bytes is x and y
+    when they arrive as numpy."""
+    from repro.core import select_features
+    from repro.dist.dekrr_spmd import _stage_packed_inputs
+
+    ds, train, solver = _tiny_ring_solver()
+    staged = _stage_packed_inputs(solver, gram_backend=gram_backend)
+    dims, nbr_idx, _ = staged["_meta"]
+    arrays = {k: v for k, v in staged.items() if isinstance(v, np.ndarray)}
+    want = sum(a.nbytes for a in arrays.values())
+    want += (arrays["feat_mask"].nbytes + nbr_idx.nbytes
+             + arrays["nbr_mask"].nbytes)
+    if gram_backend == "pallas":
+        k_slots = arrays["nbr_mask"].shape[1]
+        want += sum(arrays[k].nbytes for k in
+                    ("omega", "bias", "x", "y", "col_mask", "x_n",
+                     "col_mask_n"))
+        want += k_slots * (arrays["omega"].nbytes + arrays["bias"].nbytes)
+        assert {"gram_raw", "zy_raw", "gram_cross_raw"} <= set(arrays)
+    with obs_spans.recording() as rec:
+        pack_problem(solver, gram_backend=gram_backend)
+    assert rec.counts["pack.h2d_bytes"] == want
+
+    x, y = np.asarray(train[0].x), np.asarray(train[0].y)
+    with obs_spans.recording() as rec:
+        select_features(KEY, ds.dim, 6, 1.0, x, y, candidate_ratio=5)
+        select_features(KEY, ds.dim, 6, 1.0, jnp.asarray(x),
+                        jnp.asarray(y), candidate_ratio=5)
+    assert rec.counts["ddrf.h2d_bytes"] == x.nbytes + y.nbytes
+    sp = next(s for s in rec.spans if s.name == "ddrf.select")
+    assert sp.attrs == {"d0": 30, "D": 6, "N": x.shape[1]}
+
+
+def test_h2d_nbytes_counts_at_the_device_dtype():
+    """With x64 off a 64-bit numpy array is copied at 4 bytes an element;
+    device arrays copy nothing."""
+    a64, i64, f32 = np.ones(3), np.arange(5), np.ones(2, np.float32)
+    with jax.enable_x64(False):
+        assert obs_spans.h2d_nbytes(a64, i64, f32, jnp.ones(7)) == 12 + 20 + 8
+    with jax.enable_x64(True):
+        assert obs_spans.h2d_nbytes(a64, i64, f32) == 24 + 40 + 8
+
+
 def test_latency_recorder_lives_in_obs():
     from repro.obs.metrics import LatencyRecorder, LatencyReport
     from repro.serve import admission
@@ -605,12 +758,6 @@ def test_jsonl_and_prometheus_exports(tmp_path):
     assert tr["label"] == "j1/xla" and len(tr["residuals"]) == 4
     assert all(f in tr for f in ("active", "broadcasts", "deliveries",
                                  "bytes"))
-    prom = obs_export.to_prometheus(reg)
-    assert "bench.suites_run 2" in prom.replace("bench_suites_run",
-                                                "bench.suites_run")
-    assert "queue_depth 3" in prom          # name sanitized
-    assert 'wave_s{quantile="0.5"} 0.25' in prom
-    assert "span" not in prom               # traces are JSONL-only
 
 
 def test_provenance_names_the_device_or_raises(monkeypatch):
