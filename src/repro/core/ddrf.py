@@ -22,6 +22,7 @@ own feature map — the regime DeKRR-DDRF is designed for.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Literal
 
 import jax
@@ -76,14 +77,58 @@ def energy_scores(fmap: FeatureMap, x: jax.Array, y: jax.Array) -> jax.Array:
 def leverage_scores(fmap: FeatureMap, x: jax.Array,
                     lam: float = 1e-6) -> jax.Array:
     """Ridge leverage score per frequency (paired features are summed)."""
+    return _leverage_scores(fmap, x, lam * x.shape[-1])
+
+
+def _leverage_scores(fmap: FeatureMap, x: jax.Array, ridge) -> jax.Array:
+    """`leverage_scores` with the ridge λN given whole: a traced ridge is
+    then rounded once, as the host's product is, not formed in float32."""
     z = featurize(fmap, x)                             # [D_feat, N]
-    n = z.shape[1]
     g = z @ z.T                                        # [D_feat, D_feat]
-    reg = lam * n * jnp.eye(g.shape[0], dtype=g.dtype)
+    reg = ridge * jnp.eye(g.shape[0], dtype=g.dtype)
     # τ = diag(G (G + λN I)^{-1}) via Cholesky solve.
     sol = jax.scipy.linalg.cho_solve(
         jax.scipy.linalg.cho_factor(g + reg), g)
     return _fold_paired(jnp.diag(sol), fmap)
+
+
+# Number of times the selection program has been *traced* (not called):
+# the tests assert it does not grow over calls at one shape.
+_SELECT_TRACE_COUNT = 0
+
+
+def select_trace_count() -> int:
+    return _SELECT_TRACE_COUNT
+
+
+@partial(jax.jit, static_argnames=("dim", "num_features", "candidate_ratio",
+                                   "method", "kind", "scorer"))
+def _select(key, x, y, sigma, ridge, *, dim, num_features, candidate_ratio,
+            method, kind, scorer) -> FeatureMap:
+    """One node's selection as one program: the candidate draw, the
+    scores, the top-D (or resampled) indices and the row gather. JAX's
+    cache keys it on the shapes of x and y, so it compiles once per
+    (D, N, d); σ and the ridge are traced and change nothing. `scorer` is
+    `energy_scores` as the module holds it when called, static so that a
+    replaced one (bench/faults.py plants one) gets a program of its own
+    and not the cached one."""
+    global _SELECT_TRACE_COUNT
+    _SELECT_TRACE_COUNT += 1
+    d0 = candidate_ratio * num_features
+    k_cand, k_res = jax.random.split(key)
+    cand = sample_rff(k_cand, dim, d0, sigma, kind=kind)
+    if method == "energy":
+        scores = scorer(cand, x, y)
+    else:
+        scores = _leverage_scores(cand, x, ridge)
+    if method == "leverage_resample":
+        p = jnp.maximum(scores, 0.0)
+        p = p / jnp.sum(p)
+        idx = jax.random.choice(k_res, d0, shape=(num_features,),
+                                replace=False, p=p)
+    else:
+        idx = jnp.argsort(-scores)[:num_features]
+    return cand.subset(idx)
 
 
 def select_features(
@@ -103,32 +148,22 @@ def select_features(
     """DDRF pipeline: sample D0 = ratio·D candidates, score, select D.
 
     ``method="plain"`` returns data-independent RFF (the DKLA setting).
-    The paper follows [33] with D0/D = 20 (candidate_ratio).
+    The paper follows [33] with D0/D = 20 (candidate_ratio). The other
+    methods run as one compiled program per call (`_select`), which
+    compiles once per shape of x and y and per number of features.
     """
     if method == "plain":
         return sample_rff(key, dim, num_features, sigma, kind=kind)
 
+    if method not in ("energy", "leverage", "leverage_resample"):
+        raise ValueError(f"unknown DDRF method {method!r}")
+    if method == "energy" and y is None:
+        raise ValueError("energy scoring requires labels y")
     d0 = candidate_ratio * num_features
     with span("ddrf.select", d0=d0, D=num_features, N=int(x.shape[-1])):
         if is_recording():      # numpy x and y are copied to the device
             count("ddrf.h2d_bytes", h2d_nbytes(x, y))
-        k_cand, k_res = jax.random.split(key)
-        cand = sample_rff(k_cand, dim, d0, sigma, kind=kind)
-
-        if method == "energy":
-            if y is None:
-                raise ValueError("energy scoring requires labels y")
-            scores = energy_scores(cand, x, y)
-            idx = jnp.argsort(-scores)[:num_features]
-        elif method == "leverage":
-            scores = leverage_scores(cand, x, lam=leverage_lam)
-            idx = jnp.argsort(-scores)[:num_features]
-        elif method == "leverage_resample":
-            scores = leverage_scores(cand, x, lam=leverage_lam)
-            p = jnp.maximum(scores, 0.0)
-            p = p / jnp.sum(p)
-            idx = jax.random.choice(k_res, d0, shape=(num_features,),
-                                    replace=False, p=p)
-        else:
-            raise ValueError(f"unknown DDRF method {method!r}")
-        return cand.subset(idx)
+        return _select(key, x, y, sigma, leverage_lam * x.shape[-1],
+                       dim=dim, num_features=num_features,
+                       candidate_ratio=candidate_ratio, method=method,
+                       kind=kind, scorer=energy_scores)

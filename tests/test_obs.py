@@ -692,6 +692,43 @@ def test_pack_h2d_bytes_equal_staged_nbytes(gram_backend):
     assert sp.attrs == {"d0": 30, "D": 6, "N": x.shape[1]}
 
 
+@pytest.mark.parametrize("method", ["energy", "leverage"])
+def test_ddrf_select_span_and_h2d_bytes_every_call(tmp_path, method):
+    """Each select_features call, compiled program or not, still gives one
+    ddrf.select span with d0, D and N, on the recorder and on the
+    profiler's timeline, and a ddrf.h2d_bytes count of its numpy x and y
+    inside that span: what the benchmark's DDRF readers look for."""
+    from repro.core import select_features
+
+    ds, train, _ = _tiny_ring_solver()
+    calls = [(np.asarray(train[j].x), np.asarray(train[j].y), dims)
+             for j, dims in ((0, 6), (1, 6), (2, 4))]
+    for x, y, dims in calls:                            # compile outside
+        select_features(KEY, ds.dim, dims, 1.0, x, y, method=method,
+                        candidate_ratio=5)
+    with jax.profiler.trace(str(tmp_path)):
+        with obs_spans.recording() as rec:
+            for j, (x, y, dims) in enumerate(calls):
+                select_features(jax.random.fold_in(KEY, j), ds.dim, dims,
+                                0.5 + j, x, y, method=method,
+                                candidate_ratio=5)
+    want = [{"d0": 5 * dims, "D": dims, "N": x.shape[1]}
+            for x, _, dims in calls]
+    assert [sp.attrs for sp in rec.spans if sp.name == "ddrf.select"] == want
+    assert rec.counts["ddrf.h2d_bytes"] == sum(x.nbytes + y.nbytes
+                                               for x, y, _ in calls)
+    pre, cpre = obs_spans.TIMELINE_PREFIX, obs_spans.COUNT_PREFIX
+    timeline = _timeline_events(tmp_path)
+    spans = sorted((s, e, st) for n, s, e, st in timeline
+                   if n == pre + "ddrf.select")
+    assert [st for _, _, st in spans] == want
+    marks = sorted((s, st["n"]) for n, s, _, st in timeline
+                   if n == cpre + "ddrf.h2d_bytes")
+    assert [v for _, v in marks] == [x.nbytes + y.nbytes
+                                     for x, y, _ in calls]
+    assert all(s0 <= m < s1 for (m, _), (s0, s1, _) in zip(marks, spans))
+
+
 def test_h2d_nbytes_counts_at_the_device_dtype():
     """With x64 off a 64-bit numpy array is copied at 4 bytes an element;
     device arrays copy nothing."""

@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, strategies as st
 
+from repro.core import ddrf
 from repro.core.ddrf import (energy_scores, leverage_scores, select_features)
 from repro.core.rff import (featurize, gaussian_kernel, sample_rff)
 
@@ -93,6 +94,88 @@ def test_select_features_returns_requested_count(method):
                            candidate_ratio=10)
     assert fmap.num_frequencies == 12
     assert featurize(fmap, x).shape == (12, 128)
+
+
+def _eager_selection(key, dim, num_features, sigma, x, y, method, ratio,
+                     kind, lam=1e-6):
+    """The selection one eager op at a time: the candidate draw of the
+    key's first half, the scores, top-D (or the resample on the second
+    half), then the row gather. Returns (selected map, candidates)."""
+    k_cand, k_res = jax.random.split(key)
+    d0 = ratio * num_features
+    cand = sample_rff(k_cand, dim, d0, sigma, kind=kind)
+    x = jnp.asarray(x)
+    if method == "energy":
+        idx = jnp.argsort(-energy_scores(cand, x, jnp.asarray(y)))
+        idx = idx[:num_features]
+    else:
+        scores = leverage_scores(cand, x, lam=lam)
+        if method == "leverage":
+            idx = jnp.argsort(-scores)[:num_features]
+        else:
+            p = jnp.maximum(scores, 0.0)
+            p = p / jnp.sum(p)
+            idx = jax.random.choice(k_res, d0, shape=(num_features,),
+                                    replace=False, p=p)
+    return cand.subset(idx), cand
+
+
+@pytest.mark.parametrize("kind", ["cos_bias", "cos_sin"])
+@pytest.mark.parametrize("method", ["energy", "leverage",
+                                    "leverage_resample"])
+def test_select_features_matches_eager_composition(method, kind):
+    """The compiled selection returns the very rows the eager composition
+    does (same draws, same scores, same tie order), and each is a row of
+    the key's candidate draw."""
+    rng = np.random.default_rng(11)
+    d, n, D, ratio, sigma = 6, 240, 9, 12, 1.7
+    x = rng.uniform(size=(d, n))
+    y = np.sin(3.0 * x[0]) + x[1] ** 2
+    for seed in (0, 1):
+        key = jax.random.PRNGKey(40 + seed)
+        got = select_features(key, d, D, sigma, x, y, method=method,
+                              candidate_ratio=ratio, kind=kind)
+        want, cand = _eager_selection(key, d, D, sigma, x, y, method, ratio,
+                                      kind)
+        assert got.kind == kind and got.omega.dtype == want.omega.dtype
+        np.testing.assert_array_equal(np.asarray(got.omega),
+                                      np.asarray(want.omega))
+        if kind == "cos_bias":
+            np.testing.assert_array_equal(np.asarray(got.bias),
+                                          np.asarray(want.bias))
+        else:
+            assert got.bias is None
+        om, co = np.asarray(got.omega), np.asarray(cand.omega)
+        rows = [int(np.flatnonzero((co == r).all(axis=1))[0]) for r in om]
+        assert len(set(rows)) == D
+        if kind == "cos_bias":
+            np.testing.assert_array_equal(np.asarray(got.bias),
+                                          np.asarray(cand.bias)[rows])
+
+
+def test_select_program_traced_once_per_shape():
+    """New keys, data values and σ at one (D, N) reuse the compiled
+    selection (a stream refresh's new σ̂ does not recompile); a new
+    (D, N) traces it once more."""
+    rng = np.random.default_rng(5)
+    d, n = 5, 173
+
+    def call(seed, num_features, n_cols, sigma):
+        x = rng.normal(size=(d, n_cols))
+        y = rng.normal(size=n_cols)
+        return select_features(jax.random.PRNGKey(seed), d, num_features,
+                               sigma, x, y, candidate_ratio=7)
+
+    call(0, 11, n, 1.0)
+    before = ddrf.select_trace_count()
+    for seed, sigma in ((1, 1.0), (2, 0.37), (3, 2.9)):
+        fmap = call(seed, 11, n, sigma)
+        assert fmap.num_frequencies == 11
+    assert ddrf.select_trace_count() == before
+    call(4, 13, n + 6, 1.0)
+    assert ddrf.select_trace_count() == before + 1
+    call(5, 13, n + 6, 0.5)
+    assert ddrf.select_trace_count() == before + 1
 
 
 def test_ddrf_improves_over_plain_on_structured_target():
