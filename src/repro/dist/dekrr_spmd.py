@@ -421,6 +421,17 @@ def _to_device(*arrays) -> list:
     return [jnp.asarray(a) for a in arrays]
 
 
+def _padded_layout(solver) -> dict:
+    """The shape the batched pack pads every node to, as the `pack.stage`
+    span records it: J nodes, K neighbour slots (the topology's largest
+    degree), F_max frequencies, D_max features and N_max samples."""
+    fmaps = solver.feature_maps
+    return dict(nodes=solver.J, slots=solver.topology.max_degree,
+                f_max=max(fm.num_frequencies for fm in fmaps),
+                d_max=max(fm.num_features for fm in fmaps),
+                n_max=max(nd.num_samples for nd in solver.data))
+
+
 def _stage_packed_inputs(solver, *, gram_backend: str | None) -> dict:
     """Numpy-stage padded [J, …] inputs for the batched Eq. 17 build.
 
@@ -431,7 +442,8 @@ def _stage_packed_inputs(solver, *, gram_backend: str | None) -> dict:
     """
     if gram_backend is None:
         gram_backend = "pallas" if jax.default_backend() == "tpu" else "xla"
-    with span("pack.stage"):
+    with span("pack.stage",
+              **(_padded_layout(solver) if is_recording() else {})):
         j_nodes = solver.J
         dtype = np.asarray(solver.data[0].x).dtype
 
@@ -896,9 +908,6 @@ def _run_rounds_traced(packed: PackedProblem, theta: jax.Array,
     return lax.scan(round_fn, theta, None, length=num_rounds)
 
 
-@partial(jax.jit, static_argnames=("num_iters", "backend", "tol",
-                                   "chunk_rounds", "return_rounds",
-                                   "return_trace"))
 def solve_batched(packed: PackedProblem, num_iters: int,
                   theta0: jax.Array | None = None,
                   backend: str = "xla", *, tol: float = 0.0,
@@ -935,7 +944,26 @@ def solve_batched(packed: PackedProblem, num_iters: int,
     identical for every `chunk_rounds`. On tol-stopped solves the rounds
     after the stop (frozen rounds) record 0. Return order is
     ``(theta[, rounds][, trace])``.
+
+    The dispatch runs inside a `solve.batched` span that records the
+    packed shape every round streams: `nodes` J, `slots` K, `d_max`.
     """
+    layout = (dict(nodes=packed.num_nodes, slots=packed.num_slots,
+                   d_max=packed.max_features) if is_recording() else {})
+    with span("solve.batched", **layout):
+        return _solve_batched(packed, num_iters, theta0, backend, tol=tol,
+                              chunk_rounds=chunk_rounds,
+                              return_rounds=return_rounds,
+                              return_trace=return_trace)
+
+
+@partial(jax.jit, static_argnames=("num_iters", "backend", "tol",
+                                   "chunk_rounds", "return_rounds",
+                                   "return_trace"))
+def _solve_batched(packed: PackedProblem, num_iters: int,
+                   theta0: jax.Array | None, backend: str, *, tol: float,
+                   chunk_rounds: int | None, return_rounds: bool,
+                   return_trace: bool):
     _check_backend(backend)
     if tol < 0:
         raise ValueError(f"tol must be >= 0, got {tol}")

@@ -15,8 +15,8 @@ Architecture map
             exact vs per-round recomputation (tests/test_obs.py).
 
     HOST SIDE (stdlib clocks, injectable — R006 chokepoint)
-      pack_problem (pack.stage, pack.gram) · ddrf.select · stream
-      ingest/refresh/publish · serve waves
+      pack_problem (pack.stage, pack.gram) · solve.batched dispatch ·
+      ddrf.select · stream ingest/refresh/publish · serve waves
         └─▶ spans (repro.obs.spans: nested context-manager intervals,
             recorded only while a SpanRecorder is installed, and then
             also jax.profiler.TraceAnnotations named TIMELINE_PREFIX +

@@ -81,3 +81,28 @@ def cached_fmaps(name: str, num_nodes: int, dims: tuple,
                         candidate_ratio=candidate_ratio)
         for j in range(num_nodes)
     ]
+
+
+@functools.lru_cache(maxsize=None)
+def cached_imbalanced(name: str, num_nodes: int, dbar: int,
+                      subsample: int = 600, seed: int = 0):
+    """(dataset, train, fmaps) in the paper's Fig. 3 layout at a small
+    size: N_j = (2j−1)/J²·N dealt iid, and energy DDRF maps of
+    D_j = √N_j·J·D̄/Σ√N_i features (rounded, at least 4), so that both the
+    sample and the feature counts differ from node to node."""
+    import numpy as np
+
+    from repro.core import select_features
+    from repro.data.synthetic import (imbalanced_sizes, partition,
+                                      train_test_split_nodes)
+    ds = cached_dataset(name, subsample, seed)
+    train, _ = train_test_split_nodes(partition(
+        ds, num_nodes, mode="iid",
+        sizes=imbalanced_sizes(ds.num_samples, num_nodes), seed=seed))
+    w = np.sqrt([t.num_samples for t in train])
+    dims = np.maximum((w * num_nodes * dbar / w.sum()).round(), 4)
+    keys = jax.random.split(jax.random.PRNGKey(seed), num_nodes)
+    fmaps = [select_features(keys[j], ds.dim, int(dims[j]), 1.0, train[j].x,
+                             train[j].y, candidate_ratio=5)
+             for j in range(num_nodes)]
+    return ds, train, fmaps

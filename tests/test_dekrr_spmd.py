@@ -7,7 +7,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import REPO_ROOT, cached_fmaps, cached_split, subprocess_env
+from conftest import (REPO_ROOT, cached_fmaps, cached_imbalanced,
+                      cached_split, subprocess_env)
 from repro.core import DeKRRConfig, DeKRRSolver, circulant, erdos_renyi, star
 from repro.dist import (comm_bytes_per_round, pack_problem, solve_batched,
                         step_batched)
@@ -56,6 +57,33 @@ def test_solve_batched_scan_matches_python_loop():
         theta = step_batched(packed, theta)
     np.testing.assert_allclose(np.asarray(theta_scan), np.asarray(theta),
                                rtol=1e-9, atol=1e-12)
+
+
+@pytest.mark.parametrize("gram_backend", ["xla", "pallas"])
+def test_imbalanced_pack_and_solve_match_ragged_reference(gram_backend):
+    """The paper's Fig. 3 layout, where N_j and D_j both differ by node
+    (3…57 samples, 4…12 features): the padded pack and the batched solve,
+    to a fixed round count and to tol, give the ragged solver's θ, and
+    the padded coordinates stay zero."""
+    ds, train, fmaps = cached_imbalanced("air_quality", 10, 8)
+    dims = [fm.num_features for fm in fmaps]
+    n = sum(t.num_samples for t in train)
+    config = DeKRRConfig(lam=1e-6, c_nei=0.02 * n, tol=1e-6)
+    solver = DeKRRSolver(circulant(10, (1, 2)), fmaps, train, config)
+    packed = pack_problem(solver, gram_backend=gram_backend)
+    assert packed.max_features == max(dims) > min(dims)
+    theta = solve_batched(packed, 40)
+    ref = solver.solve(num_iters=40)
+    theta_tol, rounds = solve_batched(packed, 4000, tol=config.tol,
+                                      return_rounds=True)
+    ref_tol = solver.solve(num_iters=int(rounds))
+    assert 40 < int(rounds) < 4000
+    for th, want in ((theta, ref), (theta_tol, ref_tol)):
+        for j, dj in enumerate(dims):
+            np.testing.assert_allclose(np.asarray(th[j][:dj]),
+                                       np.asarray(want.theta[j]),
+                                       rtol=1e-9, atol=1e-12)
+            assert not np.any(np.asarray(th[j][dj:]))
 
 
 def test_circulant_packing_slot_order():

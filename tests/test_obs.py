@@ -35,7 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import REPO_ROOT, cached_fmaps, cached_split, subprocess_env
+from conftest import (REPO_ROOT, cached_fmaps, cached_imbalanced,
+                      cached_split, subprocess_env)
 from repro.core import (AsyncGossipConfig, DeKRRConfig, DeKRRSolver,
                         Topology, circulant, erdos_renyi, star)
 from repro.core.acceleration import chebyshev_solve_packed
@@ -602,6 +603,64 @@ def test_timeline_spans_nest_on_profiler_trace(tmp_path):
     assert all(g0 <= s < g1 for s, _ in h2d[:2])
     assert all(g1 <= s < p1 for s, _ in h2d[2:])
     assert sum(v for _, v in h2d) == rec.counts["pack.h2d_bytes"]
+
+
+def _imbalanced_solver():
+    """Ten nodes of 3…57 samples and 4…12 features on circulant(10,
+    (1, 2)): every axis of the packed layout is padded."""
+    _, train, fmaps = cached_imbalanced("air_quality", 10, 8)
+    return DeKRRSolver(circulant(10, (1, 2)), fmaps, train,
+                       DeKRRConfig(lam=1e-6), build_aux=False)
+
+
+@pytest.mark.parametrize("gram_backend", ["xla", "pallas"])
+def test_pack_and_solve_spans_state_the_padded_shape(tmp_path, gram_backend):
+    """pack.stage states the layout it padded to (nodes, slots, f_max,
+    d_max, n_max, as the staged arrays have them) and solve.batched the
+    packed shape each round streams (nodes, slots, d_max), on the
+    recorder and on the profiler's timeline."""
+    from repro.dist.dekrr_spmd import _stage_packed_inputs
+
+    solver = _imbalanced_solver()
+    staged = _stage_packed_inputs(solver, gram_backend=gram_backend)
+    packed = pack_problem(solver, gram_backend=gram_backend)
+    solve_batched(packed, 5)                            # compile outside
+    with jax.profiler.trace(str(tmp_path)):
+        with obs_spans.recording() as rec:
+            packed = pack_problem(solver, gram_backend=gram_backend)
+            solve_batched(packed, 5)
+    j_nodes, k_slots = staged["nbr_mask"].shape
+    stage = dict(nodes=j_nodes, slots=k_slots,
+                 f_max=staged["omega"].shape[1],
+                 d_max=staged["feat_mask"].shape[1],
+                 n_max=staged["x"].shape[2])
+    assert stage == dict(nodes=10, slots=4, f_max=12, d_max=12, n_max=57)
+    solve = dict(nodes=10, slots=4, d_max=packed.max_features)
+    assert [(sp.name, sp.attrs) for sp in rec.spans
+            if sp.name in ("pack.stage", "solve.batched")] == [
+        ("pack.stage", stage), ("solve.batched", solve)]
+    pre = obs_spans.TIMELINE_PREFIX
+    assert [(n[len(pre):], st) for n, _, _, st in _timeline_events(tmp_path)
+            if n in (pre + "pack.stage", pre + "solve.batched")] == [
+        ("pack.stage", stage), ("solve.batched", solve)]
+
+
+def test_padded_shape_not_reckoned_without_recorder(monkeypatch):
+    """Outside a recording neither span reckons its shape nor enters an
+    annotation: each stays one attribute read."""
+    import repro.dist.dekrr_spmd as spmd_mod
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("ran outside a recording")
+
+    solver = _imbalanced_solver()
+    packed = pack_problem(solver)
+    solve_batched(packed, 5)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    monkeypatch.setattr(spmd_mod, "_padded_layout", refuse)
+    monkeypatch.setattr(obs_spans.SpanRecorder, "span", refuse)
+    assert not obs_spans.is_recording()
+    solve_batched(pack_problem(solver), 5)
 
 
 def test_counts_and_timeline_do_nothing_without_recorder(monkeypatch):
