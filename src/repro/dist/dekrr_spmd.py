@@ -25,12 +25,14 @@ three layers that are pinned to the reference by parity tests
    The default `method="batched"` *computes* the Eq. 17 auxiliaries itself,
    directly in the padded [J, D_max, …] layout: one vmapped program
    (featurize → Gram blocks → coupling products → batched inverse) over
-   numpy-staged padded inputs, traced once per problem shape regardless of
-   J. The Z Zᵀ Gram blocks can optionally be routed through the fused Pallas
-   streaming kernel (`repro.kernels.rff_gram`, ``gram_backend="pallas"``,
-   the TPU default). `method="aux"` is the legacy path that copies the
-   solver's ragged reference auxiliaries (a per-node Python loop) — kept
-   for gram_fn-customized solvers and as the reference the batched build is
+   inputs padded on the device (each node's data crosses once, at its own
+   size; neighbour slots are gathered there too), traced once per problem
+   shape regardless of J. The Z Zᵀ Gram blocks can optionally be
+   routed through the fused Pallas streaming kernel
+   (`repro.kernels.rff_gram`, ``gram_backend="pallas"``, the TPU default).
+   `method="aux"` is the legacy path that copies the solver's ragged
+   reference auxiliaries (a per-node Python loop) — kept for
+   gram_fn-customized solvers and as the reference the batched build is
    regression-tested against.
 
 2. **Batched single-host execution** (`step_batched` / `solve_batched`):
@@ -257,7 +259,7 @@ def pack_problem(solver, *, method: str = "batched",
 
     ``method="batched"`` (default) computes the Eq. 17 auxiliaries directly
     in the padded layout: one vmapped featurize→Gram→inverse program over
-    numpy-staged [J, …] inputs, traced once per problem shape — no per-node
+    device-staged [J, …] inputs, traced once per problem shape — no per-node
     Python iteration over traced computation, so packing scales to large J
     (construct the solver with ``build_aux=False`` to skip the ragged
     reference build entirely). ``gram_backend`` picks how the Z Zᵀ blocks
@@ -311,7 +313,7 @@ def pack_problem(solver, *, method: str = "batched",
             return _pack_problem_from_aux(solver)
     with span("pack_problem", nodes=solver.J, method="batched"):
         staged = _stage_packed_inputs(solver, gram_backend=gram_backend)
-        return _finish_packed(staged, _build_packed_aux(**staged))
+        return _finish_packed(staged, _build_packed_aux(staged))
 
 
 def _pack_problem_from_aux(solver) -> PackedProblem:
@@ -366,37 +368,32 @@ def pack_trace_count() -> int:
     return _PACK_TRACE_COUNT
 
 
-def _stage_feature_maps(fmaps, dtype) -> dict:
-    """Numpy-stage a uniform-kind feature-map list into padded [J, …]
-    arrays: omega [J, F_max, d], bias [J, F_max], feat_idx [J, D_max]
-    (row map from raw featurize space — size F_max or 2·F_max — into the
-    packed feature space: identity for cos_bias; for cos_sin node j's
-    live rows are [0, F_j) ∪ [F_max, F_max + F_j) made contiguous),
-    feat_mask [J, D_max], and the per-node scale (√(2/F_j) or 1/√F_j).
-
-    Shared by `pack_problem`'s batched build and `repro.stream` — the
-    stream's rtol-1e-9 parity contract depends on bit-identical staging,
-    so there is exactly one copy of these conventions.
-    """
+def _uniform_kind(fmaps) -> str:
     kinds = {fm.kind for fm in fmaps}
     if len(kinds) > 1:
         raise ValueError(
             f"feature-map staging requires a uniform kind across nodes "
             f"(got {sorted(kinds)}) — mixed kinds are only supported by "
             f"the ragged pack_problem(method='aux') path")
-    kind = fmaps[0].kind
-    j_nodes = len(fmaps)
-    dim_in = fmaps[0].omega.shape[1]
-    freqs = np.array([fm.num_frequencies for fm in fmaps])
-    dims = np.array([fm.num_features for fm in fmaps])
-    f_max, d_max = int(freqs.max()), int(dims.max())
+    return fmaps[0].kind
 
-    omega = np.zeros((j_nodes, f_max, dim_in), dtype=dtype)
-    bias = np.zeros((j_nodes, f_max), dtype=dtype)
-    for j, fm in enumerate(fmaps):
-        omega[j, :freqs[j]] = np.asarray(fm.omega)
-        if fm.bias is not None:
-            bias[j, :freqs[j]] = np.asarray(fm.bias)
+
+def _feature_layout(kind: str, freqs, dtype) -> dict:
+    """The packed feature space of uniform-kind maps with `freqs`
+    frequencies each, in numpy: feat_idx [J, D_max] (row map from raw
+    featurize space — size F_max or 2·F_max — into the packed feature
+    space: identity for cos_bias; for cos_sin node j's live rows are
+    [0, F_j) ∪ [F_max, F_max + F_j) made contiguous), feat_mask
+    [J, D_max], the per-node scale (√(2/F_j) or 1/√F_j) and node_dims.
+
+    The one copy of these conventions: `repro.stream`'s staging
+    (`_stage_feature_maps`) and the pack's (`_pad_nodes`, as constants of
+    its compiled program) both read it, so the stream's rtol-1e-9 parity
+    contract sees bit-identical tables.
+    """
+    freqs = np.asarray(freqs)
+    dims = freqs if kind == "cos_bias" else 2 * freqs
+    j_nodes, f_max, d_max = len(freqs), int(freqs.max()), int(dims.max())
     feat_mask = (np.arange(d_max)[None, :] < dims[:, None]).astype(dtype)
     if kind == "cos_bias":
         feat_idx = np.broadcast_to(np.arange(d_max, dtype=np.int32),
@@ -408,17 +405,56 @@ def _stage_feature_maps(fmaps, dtype) -> dict:
             feat_idx[j, :2 * fj] = np.concatenate(
                 [np.arange(fj), f_max + np.arange(fj)])
         scale = (1.0 / np.sqrt(freqs)).astype(dtype)
-    return dict(omega=omega, bias=bias, feat_idx=feat_idx,
-                feat_mask=feat_mask, scale=scale, kind=kind,
+    return dict(feat_idx=feat_idx, feat_mask=feat_mask, scale=scale,
                 node_dims=tuple(int(v) for v in dims))
 
 
-def _to_device(*arrays) -> list:
-    """`jnp.asarray` of each array, the numpy bytes copied counted as
-    `pack.h2d_bytes` while recording."""
+def _pad_axis(a, axis: int, to: int, dtype):
+    """`a` at `dtype`, zero-padded along `axis` to length `to`."""
+    widths = [(0, 0)] * a.ndim
+    widths[axis] = (0, to - a.shape[axis])
+    return jnp.pad(jnp.asarray(a).astype(dtype), widths)
+
+
+def _pad_maps(omegas, biases, dtype) -> tuple:
+    """The nodes' maps stacked at `dtype` with zero rows to F_max: omega
+    [J, F_max, d] and bias [J, F_max] (zeros for a map without one). The
+    live rows are exact copies. Traced inside `_pad_nodes`, and run
+    eagerly by `_stage_feature_maps`."""
+    f_max = max(om.shape[0] for om in omegas)
+    omega = jnp.stack([_pad_axis(om, 0, f_max, dtype) for om in omegas])
+    bias = jnp.stack([
+        _pad_axis(np.zeros(om.shape[0], dtype) if b is None else b, 0,
+                  f_max, dtype)
+        for om, b in zip(omegas, biases)])
+    return omega, bias
+
+
+def _stage_feature_maps(fmaps, dtype) -> dict:
+    """A uniform-kind feature-map list staged as padded [J, …] device
+    arrays: omega [J, F_max, d], bias [J, F_max] (`_pad_maps`) and the
+    `_feature_layout` tables. `repro.stream` stages its maps here."""
+    kind = _uniform_kind(fmaps)
+    omega, bias = _pad_maps([fm.omega for fm in fmaps],
+                            [fm.bias for fm in fmaps], dtype)
+    freqs = [fm.num_frequencies for fm in fmaps]
+    return dict(omega=omega, bias=bias, kind=kind,
+                **_feature_layout(kind, freqs, dtype))
+
+
+def _upload(tree):
+    """`jax.device_put` of a pytree, the numpy bytes copied counted as
+    `pack.h2d_bytes` while recording; device arrays stay where they are."""
     if is_recording():
-        count("pack.h2d_bytes", h2d_nbytes(*arrays))
-    return [jnp.asarray(a) for a in arrays]
+        count("pack.h2d_bytes", h2d_nbytes(*jax.tree.leaves(tree)))
+    return jax.device_put(tree)
+
+
+def _end_on_device(tree) -> None:
+    """While recording, wait for `tree` so that the enclosing span holds
+    its device work, as the harness's traced phases do."""
+    if is_recording():
+        jax.block_until_ready(tree)
 
 
 def _padded_layout(solver) -> dict:
@@ -432,106 +468,124 @@ def _padded_layout(solver) -> dict:
                 n_max=max(nd.num_samples for nd in solver.data))
 
 
-def _stage_packed_inputs(solver, *, gram_backend: str | None) -> dict:
-    """Numpy-stage padded [J, …] inputs for the batched Eq. 17 build.
+# The node inputs `_node_aux` also reads once per neighbour slot, as
+# `<name>_n`.
+_SLOT_INPUTS = ("omega", "bias", "x", "col_mask", "feat_mask", "feat_idx",
+                "scale", "ct_nei")
 
-    All cross-node gathering (neighbor Ω/b/X/masks by slot) happens here
-    with vectorized fancy indexing, so the traced builder is a pure vmap
-    over the leading node axis — which is what makes the per-node batch-of-1
-    replay in the regression test bit-identical to the batched call.
+
+@partial(jax.jit, static_argnames=("kind", "dtype"))
+def _pad_nodes(xs, ys, omegas, biases, ct_nei, nbr_idx, *, kind, dtype):
+    """Pad and stack the nodes' logical-size arrays on the device, at
+    `dtype`, and gather each node's slot neighbours' inputs by `nbr_idx`:
+    x [J, d, N_max], y [J, N_max] (or [J, N_max, Dy]), col_mask
+    [J, N_max], omega [J, F_max, d], bias [J, F_max], the
+    `_feature_layout` tables, and `<name>_n` [J, K, …] for every name of
+    `_SLOT_INPUTS`. The padding is zeros and the live entries and the
+    gathered slots are exact copies. Every shape comes from the
+    arguments' shapes, so the program compiles once per problem shape."""
+    sizes = np.array([x.shape[1] for x in xs])
+    n_max = max(x.shape[1] for x in xs)
+    omega, bias = _pad_maps(omegas, biases, dtype)
+    layout = _feature_layout(kind, [om.shape[0] for om in omegas], dtype)
+    nodes = dict(
+        x=jnp.stack([_pad_axis(x, 1, n_max, dtype) for x in xs]),
+        y=jnp.stack([_pad_axis(y, 0, n_max, dtype) for y in ys]),
+        col_mask=(jnp.arange(n_max)[None, :]
+                  < sizes[:, None]).astype(dtype),
+        omega=omega, bias=bias,
+        feat_idx=jnp.asarray(layout["feat_idx"]),
+        feat_mask=jnp.asarray(layout["feat_mask"]),
+        scale=jnp.asarray(layout["scale"]))
+    slots = dict(nodes, ct_nei=ct_nei)
+    return dict(nodes, **{k + "_n": slots[k][nbr_idx] for k in _SLOT_INPUTS})
+
+
+def _stage_packed_inputs(solver, *, gram_backend: str | None) -> dict:
+    """Stage the batched Eq. 17 build's [J, …] inputs on the device.
+
+    The nodes' x and y, and any feature map still in numpy, cross to the
+    device once, at their own sizes, together with the small per-node
+    tables (coupling coefficients, degrees, the slot table); one compiled
+    program, `_pad_nodes`, pads and stacks them there and gathers every
+    per-slot neighbour input by `nbr_idx`, so the traced builder is a
+    pure vmap over the leading node axis — which is what makes the
+    per-node batch-of-1 replay in the regression test bit-identical to
+    the batched call. Host-side bookkeeping for `_finish_packed` rides
+    along as ``"_meta"``.
     """
     if gram_backend is None:
         gram_backend = "pallas" if jax.default_backend() == "tpu" else "xla"
     with span("pack.stage",
               **(_padded_layout(solver) if is_recording() else {})):
         j_nodes = solver.J
-        dtype = np.asarray(solver.data[0].x).dtype
-
-        maps = _stage_feature_maps(solver.feature_maps, dtype)
-        kind = maps["kind"]
-        omega, bias = maps["omega"], maps["bias"]
-        feat_idx, feat_mask = maps["feat_idx"], maps["feat_mask"]
-        scale = maps["scale"]
-        sizes = np.array([nd.num_samples for nd in solver.data])
-        n_max = int(sizes.max())
-        dim_in = solver.data[0].x.shape[0]
-
-        x = np.zeros((j_nodes, dim_in, n_max), dtype=dtype)
-        dy = solver.data[0].num_outputs if solver.data[0].y.ndim > 1 else None
-        y = np.zeros((j_nodes, n_max) if dy is None else (j_nodes, n_max, dy),
-                     dtype=dtype)
-        for j, nd in enumerate(solver.data):
-            x[j, :, :sizes[j]] = np.asarray(nd.x)
-            if dy is None:
-                y[j, :sizes[j]] = np.asarray(nd.y).reshape(-1)
-            else:
-                y[j, :sizes[j]] = np.asarray(nd.y)
-        col_mask = (np.arange(n_max)[None, :] < sizes[:, None]).astype(dtype)
-
+        fmaps = solver.feature_maps
+        kind = _uniform_kind(fmaps)
+        dtype = jax.dtypes.canonicalize_dtype(solver.data[0].x.dtype)
         ct_self, ct_nei = solver.coupling_coefficients()
-        degs = solver.topology.degrees.astype(dtype)
         nbr_idx, nbr_mask, offsets = _slot_table(solver)
-
-        gather = lambda a: a[nbr_idx]            # [J, K, …] by slot table
-        staged = dict(
-            omega=omega, bias=bias, x=x, y=y,
-            col_mask=col_mask, feat_mask=feat_mask, feat_idx=feat_idx,
-            scale=scale,
-            omega_n=gather(omega), bias_n=gather(bias), x_n=gather(x),
-            col_mask_n=gather(col_mask), feat_mask_n=gather(feat_mask),
-            feat_idx_n=gather(feat_idx), scale_n=gather(scale),
-            ct_self=ct_self.astype(dtype), ct_nei=ct_nei.astype(dtype),
-            ct_nei_n=gather(ct_nei.astype(dtype)),
-            degree=degs, nbr_mask=nbr_mask.astype(dtype),
-            lam_over_j=np.full((j_nodes,),
-                               solver.config.lam / solver.J, dtype=dtype),
-            n_total=np.full((j_nodes,), float(solver.N), dtype=dtype),
-            kind=kind,
-        )
+        num_edges = _validate_slot_table(nbr_idx, nbr_mask, j_nodes)
+        nodes, tables = _upload((
+            ([nd.x for nd in solver.data], [nd.y for nd in solver.data],
+             [fm.omega for fm in fmaps], [fm.bias for fm in fmaps]),
+            dict(ct_self=ct_self.astype(dtype), ct_nei=ct_nei.astype(dtype),
+                 degree=solver.topology.degrees.astype(dtype),
+                 nbr_idx=nbr_idx, nbr_mask=nbr_mask.astype(dtype),
+                 lam_over_j=np.full((j_nodes,), solver.config.lam / j_nodes,
+                                    dtype=dtype),
+                 n_total=np.full((j_nodes,), float(solver.N), dtype=dtype))))
+        staged = dict(_pad_nodes(*nodes, tables["ct_nei"], tables["nbr_idx"],
+                                 kind=kind, dtype=dtype), **tables)
+        del nodes           # the unpadded copies: free before the Gram pass
+        _end_on_device(staged)
     if gram_backend == "pallas" and kind == "cos_bias" and j_nodes > 0:
+        from repro.kernels.ops import _interpret_default
+
         with span("pack.gram"):
-            staged.update(_pallas_gram_blocks(staged))
-    # bookkeeping for _finish_packed (not builder inputs)
-    staged["_meta"] = (maps["node_dims"], nbr_idx, offsets)
+            gram = _pallas_gram_blocks(
+                *(staged[k] for k in ("omega", "bias", "x", "y", "col_mask",
+                                      "x_n", "col_mask_n")),
+                interpret=_interpret_default())
+            _end_on_device(gram)
+        staged.update(gram)
+    node_dims = tuple(fm.num_features for fm in fmaps)
+    staged["_meta"] = (kind, node_dims, offsets, num_edges)
     return staged
 
 
-def _pallas_gram_blocks(staged: dict) -> dict:
+@partial(jax.jit, static_argnames=("interpret",))
+def _pallas_gram_blocks(omega, bias, x, y, col_mask, x_n, col_mask_n, *,
+                        interpret):
     """Route the Eq. 17 Z Zᵀ blocks through the fused streaming Pallas
-    kernel (`repro.kernels.ops.rff_gram_batched`), unit-scale frequency
-    space: gram_jj/zy for every node and Gram(Z_{j,p}) for every slot.
-    Per-node √(2/D_j) scaling and feature masking happen in `_node_aux`.
+    kernel (`repro.kernels.ops.rff_gram_batched`) on the device-staged
+    inputs, unit-scale frequency space: gram_jj/zy for every node and
+    Gram(Z_{j,p}) for every slot, as one compiled program whose outputs
+    stay on the device (the slot axis folds into the node axis without a
+    copy). Per-node √(2/D_j) scaling and feature masking happen in
+    `_node_aux`.
     """
     from repro.kernels.ops import rff_gram_batched
 
-    omega, bias = staged["omega"], staged["bias"]
-    x, y, cm = staged["x"], staged["y"], staged["col_mask"]
-    j_nodes, k_slots = staged["nbr_mask"].shape
+    gram = partial(rff_gram_batched, interpret=interpret)
+    j_nodes, k_slots = x_n.shape[:2]
+    f_max = omega.shape[1]
     # The streaming kernel's zy accumulator is scalar-target only; for
     # multi-output ([J, n_max, Dy]) y the label term is formed in
     # `_node_aux` from the packed features instead, and the kernel only
     # supplies the Gram blocks.
-    y_kernel = y if y.ndim == 2 else np.zeros(y.shape[:2], x.dtype)
-    graw, zyraw = rff_gram_batched(*_to_device(omega, bias, x, y_kernel, cm))
-    f_max, dim_in = omega.shape[1:]
+    y_kernel = y if y.ndim == 2 else jnp.zeros(y.shape[:2], x.dtype)
+    graw, zyraw = gram(omega, bias, x, y_kernel, col_mask)
     if k_slots == 0:
-        gcross = np.zeros((j_nodes, 0, f_max, f_max), x.dtype)
-        return dict(gram_raw=np.asarray(graw), zy_raw=np.asarray(zyraw),
-                    gram_cross_raw=gcross)
+        gcross = jnp.zeros((j_nodes, 0, f_max, f_max), x.dtype)
+        return dict(gram_raw=graw, zy_raw=zyraw, gram_cross_raw=gcross)
     # Z_{j,p}: node j's map on each slot-neighbor's data, flattened (j, k)
-    om_rep = np.broadcast_to(omega[:, None], (j_nodes, k_slots) +
-                             omega.shape[1:]).reshape(-1, f_max, dim_in)
-    bi_rep = np.broadcast_to(bias[:, None],
-                             (j_nodes, k_slots, f_max)).reshape(-1, f_max)
-    x_n = staged["x_n"].reshape((-1,) + x.shape[1:])
-    cm_n = staged["col_mask_n"].reshape(-1, cm.shape[1])
-    om_rep, bi_rep, x_n, cm_n = _to_device(om_rep, bi_rep, x_n, cm_n)
-    gcross, _ = rff_gram_batched(om_rep, bi_rep, x_n,
-                                 jnp.zeros(cm_n.shape, x.dtype), cm_n)
-    return dict(
-        gram_raw=np.asarray(graw), zy_raw=np.asarray(zyraw),
-        gram_cross_raw=np.asarray(gcross).reshape(
-            j_nodes, k_slots, f_max, f_max))
+    cm_n = col_mask_n.reshape(-1, col_mask.shape[1])
+    gcross, _ = gram(jnp.repeat(omega, k_slots, axis=0),
+                     jnp.repeat(bias, k_slots, axis=0),
+                     x_n.reshape((-1,) + x.shape[1:]),
+                     jnp.zeros(cm_n.shape, x.dtype), cm_n)
+    return dict(gram_raw=graw, zy_raw=zyraw,
+                gram_cross_raw=gcross.reshape(j_nodes, k_slots, f_max, f_max))
 
 
 def _gauss_jordan_inv(a: jax.Array) -> jax.Array:
@@ -641,22 +695,20 @@ def _vmapped_node_aux(kind, **arrays):
     return jax.vmap(partial(_node_aux, kind=kind))(**arrays)
 
 
-def _build_packed_aux(*, kind, _meta=None, **staged):
+def _build_packed_aux(staged: dict):
     """One traced program for the whole network (trace count independent of
     J) — see `_vmapped_node_aux` for the counter the regression test pins."""
-    return _vmapped_node_aux(kind=kind, **dict(zip(
-        staged, _to_device(*staged.values()))))
+    arrays = {k: v for k, v in staged.items()
+              if k not in ("_meta", "nbr_idx")}
+    return _vmapped_node_aux(kind=staged["_meta"][0], **arrays)
 
 
 def _finish_packed(staged: dict, built) -> PackedProblem:
     g, d, s, p = built
-    dims, nbr_idx, offsets = staged["_meta"]
-    num_edges = _validate_slot_table(nbr_idx, staged["nbr_mask"], len(dims))
-    theta_mask, nbr_idx_dev, nbr_mask = _to_device(
-        staged["feat_mask"], nbr_idx, staged["nbr_mask"])
+    _, dims, offsets, num_edges = staged["_meta"]
     return PackedProblem(
-        g=g, d=d, s=s, p=p,
-        theta_mask=theta_mask, nbr_idx=nbr_idx_dev, nbr_mask=nbr_mask,
+        g=g, d=d, s=s, p=p, theta_mask=staged["feat_mask"],
+        nbr_idx=staged["nbr_idx"], nbr_mask=staged["nbr_mask"],
         offsets=offsets, node_dims=dims, num_edges_directed=num_edges,
     )
 
@@ -668,14 +720,10 @@ def _pack_problem_pernode(solver, *, gram_backend: str | None = None
     batch-of-1 call per node. `pack_problem(method="batched")` must produce
     bit-identical contents (tests/test_dist_property.py)."""
     staged = _stage_packed_inputs(solver, gram_backend=gram_backend)
-    meta, kind = staged.pop("_meta"), staged.pop("kind")
-    parts = [
-        _build_packed_aux(kind=kind, **{k: v[j:j + 1]
-                                        for k, v in staged.items()})
-        for j in range(solver.J)
-    ]
+    parts = [_build_packed_aux({k: v if k == "_meta" else v[j:j + 1]
+                                for k, v in staged.items()})
+             for j in range(solver.J)]
     built = tuple(jnp.concatenate(col, axis=0) for col in zip(*parts))
-    staged.update(_meta=meta, kind=kind)
     return _finish_packed(staged, built)
 
 

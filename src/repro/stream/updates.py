@@ -103,8 +103,9 @@ class StreamAux:
 
     Staged feature maps (what lets ANY node featurize a minibatch in one
     uniform padded program): omega [J, F_max, dim], bias [J, F_max],
-    feat_idx [J, D_max], scale [J] — `repro.dist._stage_packed_inputs`
-    conventions exactly.
+    feat_idx [J, D_max], scale [J] — staged by
+    `repro.dist._stage_feature_maps`, which shares its padding
+    (`_pad_maps`) and its tables (`_feature_layout`) with the pack.
 
     Scalars / metadata: n_live (accumulated network sample count — the
     1/N used at materialization), nu (the pinned absolute ridge λ·n_ref/J),
@@ -163,8 +164,10 @@ def reference_lam(aux: StreamAux) -> float:
 
 
 # --------------------------------------------------------------------------
-# Layout helpers (feature-map staging is shared with pack_problem —
-# repro.dist._stage_feature_maps — so the two can never drift apart)
+# Layout helpers (feature-map padding and tables are pack_problem's own —
+# repro.dist._pad_maps and _feature_layout — and
+# tests/test_obs.py::test_device_staged_inputs_equal_numpy_staging pins
+# both stagings to a numpy padding)
 # --------------------------------------------------------------------------
 def _ingest_tables(nbr_idx: np.ndarray, nbr_mask: np.ndarray,
                    u_self: np.ndarray, u_cross: np.ndarray,

@@ -38,7 +38,7 @@ import pytest
 from conftest import (REPO_ROOT, cached_fmaps, cached_imbalanced,
                       cached_split, subprocess_env)
 from repro.core import (AsyncGossipConfig, DeKRRConfig, DeKRRSolver,
-                        Topology, circulant, erdos_renyi, star)
+                        NodeData, Topology, circulant, erdos_renyi, star)
 from repro.core.acceleration import chebyshev_solve_packed
 from repro.core.async_gossip import activation_masks, censor_schedule
 from repro.dist import (async_solve_batched, async_step_batched,
@@ -599,18 +599,112 @@ def test_timeline_spans_nest_on_profiler_trace(tmp_path):
              if n.startswith(cpre)]
     assert {n for n, _, _ in marks} <= {"pack.h2d_bytes", obs_spans.COMPILES}
     h2d = [(s, v) for n, s, v in marks if n == "pack.h2d_bytes"]
-    assert len(h2d) == 4        # Gram pass ×2, then the build and finish
-    assert all(g0 <= s < g1 for s, _ in h2d[:2])
-    assert all(g1 <= s < p1 for s, _ in h2d[2:])
+    assert len(h2d) == 1        # the one upload, inside pack.stage
+    assert all(s0 <= s < s1 for s, _ in h2d)
     assert sum(v for _, v in h2d) == rec.counts["pack.h2d_bytes"]
 
 
-def _imbalanced_solver():
+def _imbalanced_solver(host_data: bool = False):
     """Ten nodes of 3…57 samples and 4…12 features on circulant(10,
-    (1, 2)): every axis of the packed layout is padded."""
+    (1, 2)): every axis of the packed layout is padded. With `host_data`
+    each node's x and y are numpy, as a deployment hands them over."""
     _, train, fmaps = cached_imbalanced("air_quality", 10, 8)
+    if host_data:
+        train = [NodeData(x=np.asarray(t.x), y=np.asarray(t.y))
+                 for t in train]
     return DeKRRSolver(circulant(10, (1, 2)), fmaps, train,
                        DeKRRConfig(lam=1e-6), build_aux=False)
+
+
+def _pack_table_nbytes(solver) -> int:
+    """Bytes of the small per-node tables the batched pack uploads besides
+    the data: the slot table (int32 nbr_idx, float nbr_mask, [J, K]) and
+    five [J] floats (ct_self, ct_nei, degree, λ/J, N)."""
+    j_nodes, k_slots = solver.J, solver.topology.max_degree
+    item = np.dtype(jax.dtypes.canonicalize_dtype(
+        solver.data[0].x.dtype)).itemsize
+    return j_nodes * k_slots * (4 + item) + 5 * j_nodes * item
+
+
+@pytest.mark.parametrize("gram_backend", ["xla", "pallas"])
+def test_pack_uploads_each_nodes_data_once(gram_backend):
+    """Where every padded axis is live, pack.h2d_bytes is the nodes'
+    logical x and y plus the small tables: no padded or per-slot copy
+    crosses, so it is far from the K-fold neighbour copies it once held."""
+    solver = _imbalanced_solver(host_data=True)
+    logical = sum(nd.x.nbytes + nd.y.nbytes for nd in solver.data)
+    pack_problem(solver, gram_backend=gram_backend)    # compile outside
+    with obs_spans.recording() as rec:
+        pack_problem(solver, gram_backend=gram_backend)
+    sent = rec.counts["pack.h2d_bytes"]
+    assert sent == logical + _pack_table_nbytes(solver)
+    assert sent < 2 * logical
+    assert rec.counts[obs_spans.COMPILES] == 0
+
+
+@pytest.mark.parametrize("gram_backend", ["xla", "pallas"])
+def test_batched_pack_matches_pernode_loop_on_padded_layout(gram_backend):
+    """On the imbalanced layout (masks live on every axis) the batched
+    pack equals its batch-of-1 per-node replay bit for bit, with either
+    Gram backend."""
+    from repro.dist.dekrr_spmd import _pack_problem_pernode
+
+    solver = _imbalanced_solver(host_data=True)
+    packed = pack_problem(solver, gram_backend=gram_backend)
+    loop = _pack_problem_pernode(solver, gram_backend=gram_backend)
+    for name in ("g", "d", "s", "p", "theta_mask", "nbr_idx", "nbr_mask"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(packed, name)), np.asarray(getattr(loop, name)),
+            err_msg=f"{name}: batched != per-node loop")
+    assert packed.node_dims == loop.node_dims == tuple(
+        fm.num_features for fm in solver.feature_maps)
+    assert packed.num_edges_directed == loop.num_edges_directed == 40
+
+
+def test_device_staged_inputs_equal_numpy_staging():
+    """The device-side padding and slot gathers are exact copies: x, y,
+    col_mask, omega and bias equal a numpy padding of each node's arrays,
+    as does `_stage_feature_maps` (the stream's staging, which shares the
+    map padding); the feature tables equal the stream's; and every
+    gathered `<name>_n` equals numpy's a[nbr_idx]."""
+    from repro.dist.dekrr_spmd import (_SLOT_INPUTS, _slot_table,
+                                       _stage_feature_maps,
+                                       _stage_packed_inputs)
+
+    solver = _imbalanced_solver(host_data=True)
+    staged = _stage_packed_inputs(solver, gram_backend="xla")
+    dtype = solver.data[0].x.dtype
+    sizes = [nd.num_samples for nd in solver.data]
+    j_nodes, n_max, dim = solver.J, max(sizes), solver.data[0].x.shape[0]
+    freqs = [fm.num_frequencies for fm in solver.feature_maps]
+    want = dict(x=np.zeros((j_nodes, dim, n_max), dtype),
+                y=np.zeros((j_nodes, n_max), dtype),
+                col_mask=np.zeros((j_nodes, n_max), dtype),
+                omega=np.zeros((j_nodes, max(freqs), dim), dtype),
+                bias=np.zeros((j_nodes, max(freqs)), dtype))
+    for j, (nd, fm) in enumerate(zip(solver.data, solver.feature_maps)):
+        want["x"][j, :, :sizes[j]] = nd.x
+        want["y"][j, :sizes[j]] = nd.y
+        want["col_mask"][j, :sizes[j]] = 1.0
+        want["omega"][j, :freqs[j]] = np.asarray(fm.omega)
+        want["bias"][j, :freqs[j]] = np.asarray(fm.bias)
+    maps = _stage_feature_maps(solver.feature_maps, dtype)
+    for name in ("omega", "bias"):
+        np.testing.assert_array_equal(np.asarray(maps[name]), want[name],
+                                      err_msg="stream " + name)
+    want.update({k: maps[k] for k in ("feat_mask", "feat_idx", "scale")})
+    want["ct_nei"] = solver.coupling_coefficients()[1].astype(dtype)
+    for name, ref in want.items():
+        got = np.asarray(staged[name])
+        assert got.dtype == ref.dtype, name
+        np.testing.assert_array_equal(got, ref, err_msg=name)
+
+    nbr_idx = _slot_table(solver)[0]
+    np.testing.assert_array_equal(np.asarray(staged["nbr_idx"]), nbr_idx)
+    for name in _SLOT_INPUTS:
+        got, ref = np.asarray(staged[name + "_n"]), want[name][nbr_idx]
+        assert got.shape == (j_nodes, 4) + want[name].shape[1:], name
+        np.testing.assert_array_equal(got, ref, err_msg=name + "_n")
 
 
 @pytest.mark.parametrize("gram_backend", ["xla", "pallas"])
@@ -716,27 +810,22 @@ def test_compiles_count_a_forced_recompile():
 
 @pytest.mark.parametrize("gram_backend", ["xla", "pallas"])
 def test_pack_h2d_bytes_equal_staged_nbytes(gram_backend):
-    """pack.h2d_bytes is every numpy byte the batched pack uploads: the
-    Gram pass's inputs (Pallas only), the staged builder inputs, and the
-    finished problem's masks and slot table; ddrf.h2d_bytes is x and y
-    when they arrive as numpy."""
+    """pack.h2d_bytes is every numpy byte the batched pack uploads: each
+    node's numpy x and y once, at its own size, and the small tables, in
+    one upload with either Gram backend (padding, slot gathers and the
+    Gram pass's inputs are formed on the device); ddrf.h2d_bytes is x and
+    y when they arrive as numpy."""
     from repro.core import select_features
-    from repro.dist.dekrr_spmd import _stage_packed_inputs
 
     ds, train, solver = _tiny_ring_solver()
-    staged = _stage_packed_inputs(solver, gram_backend=gram_backend)
-    dims, nbr_idx, _ = staged["_meta"]
-    arrays = {k: v for k, v in staged.items() if isinstance(v, np.ndarray)}
-    want = sum(a.nbytes for a in arrays.values())
-    want += (arrays["feat_mask"].nbytes + nbr_idx.nbytes
-             + arrays["nbr_mask"].nbytes)
-    if gram_backend == "pallas":
-        k_slots = arrays["nbr_mask"].shape[1]
-        want += sum(arrays[k].nbytes for k in
-                    ("omega", "bias", "x", "y", "col_mask", "x_n",
-                     "col_mask_n"))
-        want += k_slots * (arrays["omega"].nbytes + arrays["bias"].nbytes)
-        assert {"gram_raw", "zy_raw", "gram_cross_raw"} <= set(arrays)
+    host = [NodeData(x=np.asarray(t.x), y=np.asarray(t.y)) for t in train]
+    want = _pack_table_nbytes(solver)
+    with obs_spans.recording() as rec:
+        pack_problem(solver, gram_backend=gram_backend)  # data on device
+    assert rec.counts["pack.h2d_bytes"] == want
+    solver = DeKRRSolver(solver.topology, solver.feature_maps, host,
+                         solver.config, build_aux=False)
+    want += sum(nd.x.nbytes + nd.y.nbytes for nd in host)
     with obs_spans.recording() as rec:
         pack_problem(solver, gram_backend=gram_backend)
     assert rec.counts["pack.h2d_bytes"] == want
