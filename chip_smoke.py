@@ -10,7 +10,7 @@ at d = 77, D̄ = 130 energy-selected DDRF features per node, λ, σ and c as
 in `benchmarks/common.py`. It runs the normal entry points in float32
 with x64 off, the TPU's precision:
 
-    select_features → pack_problem (Pallas Gram kernel)
+    select_features → pack_problem (one vmapped Eq. 17 build)
       → solve_batched(backend="pallas_fused", tol) and, for comparison,
         solve_batched(backend="xla", tol)
       → StreamingDeKRR → SnapshotRegistry.publish_from
@@ -192,7 +192,7 @@ def one_chip(seed: int) -> None:
                          DeKRRConfig(lam=LAM, c_nei=C_NEI * n),
                          build_aux=False)
 
-    packed = timed("pack_problem (Pallas Gram)",
+    packed = timed("pack_problem (Eq. 17 build)",
                    lambda: pack_problem(solver))
     require(packed.g.dtype == jnp.float32, f"packed dtype {packed.g.dtype}")
 
@@ -307,7 +307,7 @@ def four_chips(seed: int, devices) -> None:
     solver = DeKRRSolver(topology, fmaps, train,
                          DeKRRConfig(lam=LAM, c_nei=C_NEI * n),
                          build_aux=False)
-    packed = timed("pack_problem (Pallas Gram)",
+    packed = timed("pack_problem (Eq. 17 build)",
                    lambda: pack_problem(solver))
     _, _, rho = host_reference(topology, fmaps, train, C_NEI * n)
     # The SPMD program runs each node's round with the same f32 operations

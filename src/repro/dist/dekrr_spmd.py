@@ -27,9 +27,8 @@ three layers that are pinned to the reference by parity tests
    (featurize → Gram blocks → coupling products → batched inverse) over
    inputs padded on the device (each node's data crosses once, at its own
    size; neighbour slots are gathered there too), traced once per problem
-   shape regardless of J. The Z Zᵀ Gram blocks can optionally be
-   routed through the fused Pallas streaming kernel
-   (`repro.kernels.rff_gram`, ``gram_backend="pallas"``, the TPU default).
+   shape regardless of J. Its Z Zᵀ Gram blocks are formed from the
+   features that program computes for d and P.
    `method="aux"` is the legacy path that copies the solver's ragged
    reference auxiliaries (a per-node Python loop) — kept for
    gram_fn-customized solvers and as the reference the batched build is
@@ -253,8 +252,7 @@ def _slot_table(solver):
 _PACK_METHODS = ("batched", "aux")
 
 
-def pack_problem(solver, *, method: str = "batched",
-                 gram_backend: str | None = None) -> PackedProblem:
+def pack_problem(solver, *, method: str = "batched") -> PackedProblem:
     """Build a `PackedProblem` from a `DeKRRSolver`.
 
     ``method="batched"`` (default) computes the Eq. 17 auxiliaries directly
@@ -262,9 +260,8 @@ def pack_problem(solver, *, method: str = "batched",
     device-staged [J, …] inputs, traced once per problem shape — no per-node
     Python iteration over traced computation, so packing scales to large J
     (construct the solver with ``build_aux=False`` to skip the ragged
-    reference build entirely). ``gram_backend`` picks how the Z Zᵀ blocks
-    are computed: "xla" (batched GEMM) or "pallas" (the fused streaming
-    `repro.kernels.rff_gram` kernel; default on TPU, cos_bias maps only).
+    reference build entirely). The Z Zᵀ Gram blocks come from the same
+    features as d and P, inside that one program.
 
     ``method="aux"`` copies the solver's ragged reference auxiliaries
     (`solver.aux`, the per-node loop) — bit-identical to the reference, and
@@ -273,8 +270,6 @@ def pack_problem(solver, *, method: str = "batched",
     if method not in _PACK_METHODS:
         raise ValueError(f"method must be one of {_PACK_METHODS}, "
                          f"got {method!r}")
-    if gram_backend not in (None, "xla", "pallas"):
-        raise ValueError(f"unknown gram_backend {gram_backend!r}")
     kinds = {fm.kind for fm in solver.feature_maps}
     has_bags = any(nd.bags is not None for nd in solver.data)
     if method == "batched" and (
@@ -288,14 +283,6 @@ def pack_problem(solver, *, method: str = "batched",
                        "applies"
                   if has_bags
                   else f"the solver mixes feature kinds {sorted(kinds)}")
-        if gram_backend == "pallas":
-            raise ValueError(
-                f"pack_problem(gram_backend='pallas') is impossible here: "
-                f"{reason}, which only the ragged method='aux' build "
-                f"honors — and the aux path computes its Gram blocks "
-                f"through the solver, ignoring gram_backend. Drop "
-                f"gram_backend or use a uniform cos_bias solver without "
-                f"gram_fn.")
         warnings.warn(
             f"pack_problem(method='batched') downgraded to method='aux': "
             f"{reason}. The aux build runs a per-node Python loop over "
@@ -303,16 +290,10 @@ def pack_problem(solver, *, method: str = "batched",
             f"slow at scale.", UserWarning, stacklevel=2)
         method = "aux"          # only the ragged build honors those
     if method == "aux":
-        if gram_backend == "pallas":
-            raise ValueError(
-                "pack_problem(method='aux') copies the solver's ragged "
-                "reference auxiliaries and ignores gram_backend — "
-                "gram_backend='pallas' would silently not be honored. "
-                "Use method='batched' for the Pallas streaming Gram path.")
         with span("pack_problem", nodes=solver.J, method="aux"):
             return _pack_problem_from_aux(solver)
     with span("pack_problem", nodes=solver.J, method="batched"):
-        staged = _stage_packed_inputs(solver, gram_backend=gram_backend)
+        staged = _stage_packed_inputs(solver)
         return _finish_packed(staged, _build_packed_aux(staged))
 
 
@@ -501,7 +482,7 @@ def _pad_nodes(xs, ys, omegas, biases, ct_nei, nbr_idx, *, kind, dtype):
     return dict(nodes, **{k + "_n": slots[k][nbr_idx] for k in _SLOT_INPUTS})
 
 
-def _stage_packed_inputs(solver, *, gram_backend: str | None) -> dict:
+def _stage_packed_inputs(solver) -> dict:
     """Stage the batched Eq. 17 build's [J, …] inputs on the device.
 
     The nodes' x and y, and any feature map still in numpy, cross to the
@@ -514,8 +495,6 @@ def _stage_packed_inputs(solver, *, gram_backend: str | None) -> dict:
     the batched call. Host-side bookkeeping for `_finish_packed` rides
     along as ``"_meta"``.
     """
-    if gram_backend is None:
-        gram_backend = "pallas" if jax.default_backend() == "tpu" else "xla"
     with span("pack.stage",
               **(_padded_layout(solver) if is_recording() else {})):
         j_nodes = solver.J
@@ -536,56 +515,10 @@ def _stage_packed_inputs(solver, *, gram_backend: str | None) -> dict:
                  n_total=np.full((j_nodes,), float(solver.N), dtype=dtype))))
         staged = dict(_pad_nodes(*nodes, tables["ct_nei"], tables["nbr_idx"],
                                  kind=kind, dtype=dtype), **tables)
-        del nodes           # the unpadded copies: free before the Gram pass
         _end_on_device(staged)
-    if gram_backend == "pallas" and kind == "cos_bias" and j_nodes > 0:
-        from repro.kernels.ops import _interpret_default
-
-        with span("pack.gram"):
-            gram = _pallas_gram_blocks(
-                *(staged[k] for k in ("omega", "bias", "x", "y", "col_mask",
-                                      "x_n", "col_mask_n")),
-                interpret=_interpret_default())
-            _end_on_device(gram)
-        staged.update(gram)
     node_dims = tuple(fm.num_features for fm in fmaps)
     staged["_meta"] = (kind, node_dims, offsets, num_edges)
     return staged
-
-
-@partial(jax.jit, static_argnames=("interpret",))
-def _pallas_gram_blocks(omega, bias, x, y, col_mask, x_n, col_mask_n, *,
-                        interpret):
-    """Route the Eq. 17 Z Zᵀ blocks through the fused streaming Pallas
-    kernel (`repro.kernels.ops.rff_gram_batched`) on the device-staged
-    inputs, unit-scale frequency space: gram_jj/zy for every node and
-    Gram(Z_{j,p}) for every slot, as one compiled program whose outputs
-    stay on the device (the slot axis folds into the node axis without a
-    copy). Per-node √(2/D_j) scaling and feature masking happen in
-    `_node_aux`.
-    """
-    from repro.kernels.ops import rff_gram_batched
-
-    gram = partial(rff_gram_batched, interpret=interpret)
-    j_nodes, k_slots = x_n.shape[:2]
-    f_max = omega.shape[1]
-    # The streaming kernel's zy accumulator is scalar-target only; for
-    # multi-output ([J, n_max, Dy]) y the label term is formed in
-    # `_node_aux` from the packed features instead, and the kernel only
-    # supplies the Gram blocks.
-    y_kernel = y if y.ndim == 2 else jnp.zeros(y.shape[:2], x.dtype)
-    graw, zyraw = gram(omega, bias, x, y_kernel, col_mask)
-    if k_slots == 0:
-        gcross = jnp.zeros((j_nodes, 0, f_max, f_max), x.dtype)
-        return dict(gram_raw=graw, zy_raw=zyraw, gram_cross_raw=gcross)
-    # Z_{j,p}: node j's map on each slot-neighbor's data, flattened (j, k)
-    cm_n = col_mask_n.reshape(-1, col_mask.shape[1])
-    gcross, _ = gram(jnp.repeat(omega, k_slots, axis=0),
-                     jnp.repeat(bias, k_slots, axis=0),
-                     x_n.reshape((-1,) + x.shape[1:]),
-                     jnp.zeros(cm_n.shape, x.dtype), cm_n)
-    return dict(gram_raw=graw, zy_raw=zyraw,
-                gram_cross_raw=gcross.reshape(j_nodes, k_slots, f_max, f_max))
 
 
 def _gauss_jordan_inv(a: jax.Array) -> jax.Array:
@@ -619,8 +552,7 @@ def _featurize_raw(omega, bias, x, kind):
 def _node_aux(omega, bias, x, y, col_mask, feat_mask, feat_idx, scale,
               omega_n, bias_n, x_n, col_mask_n, feat_mask_n, feat_idx_n,
               scale_n, ct_self, ct_nei, ct_nei_n, degree, nbr_mask,
-              lam_over_j, n_total, *, kind,
-              gram_raw=None, zy_raw=None, gram_cross_raw=None):
+              lam_over_j, n_total, *, kind):
     """Eq. 17 auxiliaries for ONE node in the padded layout (vmapped over
     the node axis by `_build_packed_aux`). All neighbor inputs arrive
     pre-gathered per slot ([K, …]); masked slots carry nbr_mask 0 and the
@@ -653,25 +585,14 @@ def _node_aux(omega, bias, x, y, col_mask, feat_mask, feat_idx, scale,
         # mult+sum rather than a matvec: XLA's gemv rounds differently at
         # different batch sizes, this form is batch-invariant (regression
         # replay in tests/test_dist_property.py)
-        d_vec_z = jnp.sum(z * y[None, :], axis=1) / n_total
+        d_vec = jnp.sum(z * y[None, :], axis=1) / n_total
     else:
         # multi-output: same batch-invariant mult+sum per output column
-        d_vec_z = jnp.sum(z[:, :, None] * y[None, :, :], axis=1) / n_total
+        d_vec = jnp.sum(z[:, :, None] * y[None, :, :], axis=1) / n_total
 
-    if gram_raw is not None:
-        # Pallas streaming kernel output (unit-scale frequency space ==
-        # packed feature space for cos_bias); mask + scale here. The
-        # kernel's zy accumulator only exists for scalar targets.
-        fouter = feat_mask[:, None] * feat_mask[None, :]
-        gram_jj = gram_raw * scale**2 * fouter
-        d_vec = (zy_raw * scale * feat_mask / n_total
-                 if y.ndim == 1 else d_vec_z)
-        gram_cross = (gram_cross_raw * scale**2 * fouter[None])
-    else:
-        gram_jj = jnp.einsum("an,bn->ab", z, z, precision=hi)
-        d_vec = d_vec_z
-        gram_cross = jnp.einsum("kan,kbn->kab", z_j_on_n, z_j_on_n,
-                                precision=hi)
+    gram_jj = jnp.einsum("an,bn->ab", z, z, precision=hi)
+    gram_cross = jnp.einsum("kan,kbn->kab", z_j_on_n, z_j_on_n,
+                            precision=hi)
 
     a = (1.0 / n_total + 2.0 * ct_self + degree * ct_nei) * gram_jj
     a = a + lam_over_j * jnp.diag(feat_mask)
@@ -713,13 +634,12 @@ def _finish_packed(staged: dict, built) -> PackedProblem:
     )
 
 
-def _pack_problem_pernode(solver, *, gram_backend: str | None = None
-                          ) -> PackedProblem:
+def _pack_problem_pernode(solver) -> PackedProblem:
     """The removed per-node Python loop, kept as the regression target: the
     same staged inputs and the same vmapped program, but replayed one
     batch-of-1 call per node. `pack_problem(method="batched")` must produce
     bit-identical contents (tests/test_dist_property.py)."""
-    staged = _stage_packed_inputs(solver, gram_backend=gram_backend)
+    staged = _stage_packed_inputs(solver)
     parts = [_build_packed_aux({k: v if k == "_meta" else v[j:j + 1]
                                 for k, v in staged.items()})
              for j in range(solver.J)]

@@ -2,7 +2,7 @@
 
 * rff_gram.py        — fused RFF featurize + streaming Gram (the paper's
                        Eq. 17 pre-iteration hot-spot; features never leave
-                       VMEM)
+                       VMEM), per node: a ragged solver's `gram_fn`
 * rff_features.py    — fused featurize for the cross-feature exchange
 * dekrr_step.py      — fused packed Eq. 19 round for all J nodes (slot-table
                        neighbor gather + Σ P θ reduction + G GEMM, θ
@@ -19,8 +19,7 @@ oracles every kernel is allclose-tested against.
 """
 from repro.kernels import ops
 from repro.kernels.ops import (dekrr_solve, dekrr_step, flash_decode,
-                               gram_fn_for_solver, rff_features, rff_gram,
-                               rff_gram_batched)
+                               gram_fn_for_solver, rff_features, rff_gram)
 
 __all__ = ["dekrr_solve", "dekrr_step", "flash_decode", "gram_fn_for_solver",
-           "ops", "rff_features", "rff_gram", "rff_gram_batched"]
+           "ops", "rff_features", "rff_gram"]

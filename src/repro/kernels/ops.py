@@ -622,43 +622,6 @@ def dekrr_cheb_solve(g: jax.Array, d: jax.Array, s: jax.Array,
                                  trace=trace, interpret=interpret)
 
 
-@partial(jax.jit, static_argnames=("block_n", "interpret"))
-def rff_gram_batched(omega: jax.Array, bias: jax.Array, x: jax.Array,
-                     y: jax.Array, col_mask: jax.Array, *,
-                     block_n: int = 1024,
-                     interpret: bool | None = None
-                     ) -> tuple[jax.Array, jax.Array]:
-    """vmapped fused streaming Gram over a leading node axis (cos_bias, the
-    unit-scale form): omega [J, F, d], bias [J, F], x [J, d, N], y [J, N],
-    col_mask [J, N] → (gram [J, F, F], zy [J, F]) with Z = cos(Ω X + b).
-
-    The per-node √(2/D_j) scale is *not* applied (it is a per-node constant,
-    which a single pallas_call cannot close over) — callers fold it in as
-    s_j²·gram / s_j·zy. Rows of padded frequencies come out as cos(0) = 1
-    and must be masked by the caller; padded *columns* are masked here.
-    Used by `repro.dist.pack_problem` for the batched Eq. 17 Z Zᵀ blocks.
-    """
-    if interpret is None:
-        interpret = _interpret_default()
-    _check_tpu_dtype(interpret, omega, bias, x, y)
-    f_feat, n = omega.shape[1], x.shape[2]
-
-    bn = min(block_n, max(128, 1 << (n - 1).bit_length()))
-    estimate_rff_gram(d_feat=_pad_dim(f_feat, 8),
-                      d_in=_pad_dim(omega.shape[2], 128), block_n=bn,
-                      itemsize=jnp.dtype(x.dtype).itemsize).check()
-    omega_p = _pad_to(_pad_to(omega, 1, 8), 2, 128).astype(x.dtype)
-    bias_p = _pad_to(bias[..., None], 1, 8).astype(x.dtype)
-    x_p = _pad_to(_pad_to(x, 1, 128), 2, bn)
-    y_p = _pad_to(y[:, None, :].astype(x.dtype), 2, bn)
-    mask_p = _pad_to(col_mask[:, None, :].astype(x.dtype), 2, bn)
-
-    gram, zy = jax.vmap(
-        partial(rff_gram_pallas, scale=1.0, block_n=bn, interpret=interpret)
-    )(omega_p, bias_p, x_p, y_p, mask_p)
-    return gram[:, :f_feat, :f_feat], zy[:, :f_feat, 0]
-
-
 # ---------------------------------------------------------------- integration
 def gram_fn_for_solver(fmap: FeatureMap, x: jax.Array) -> jax.Array:
     """Drop-in ``gram_fn`` for DeKRRSolver: computes Z(Ω, X) Z(Ω, X)ᵀ with the

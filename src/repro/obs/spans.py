@@ -1,7 +1,7 @@
 """Host-side trace spans and counters: nested wall-clock intervals
-around the runtimes' staging work (`pack_problem` and its `pack.stage` /
-`pack.gram`, the `solve.batched` dispatch, `ddrf.select`, stream
-ingest/refresh/publish, serve waves).
+around the runtimes' staging work (`pack_problem` and its `pack.stage`,
+the `solve.batched` dispatch, `ddrf.select`, stream ingest/refresh/publish,
+serve waves).
 
 Spans measure *host* work — tracing/compile/staging/queueing — never the
 device-side solve rounds (those are the on-device `return_trace=`

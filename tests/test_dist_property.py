@@ -276,7 +276,7 @@ def test_batched_pack_matches_reference_aux_pack():
 
 
 # --------------------------------------------------------------------------
-# pack_problem downgrade contract: warn, never silently ignore gram_backend
+# pack_problem downgrade contract: warn when the batched build is swapped
 # --------------------------------------------------------------------------
 def _gram_fn_solver():
     """A solver the batched build cannot honor (custom gram_fn)."""
@@ -310,16 +310,6 @@ def test_pack_problem_aux_explicitly_requested_does_not_warn():
         pack_problem(solver, method="aux")
     assert not [c for c in caught if "downgraded" in str(c.message)], \
         "explicit method='aux' is not a downgrade and must not warn"
-
-
-def test_pack_problem_raises_when_pallas_gram_would_be_ignored():
-    """gram_backend="pallas" on a path that cannot run the streaming Gram
-    kernel must raise, never silently compute the blocks elsewhere."""
-    solver = _gram_fn_solver()
-    with pytest.raises(ValueError, match="gram_fn"):
-        pack_problem(solver, gram_backend="pallas")
-    with pytest.raises(ValueError, match="ignores gram_backend"):
-        pack_problem(solver, method="aux", gram_backend="pallas")
 
 
 # --------------------------------------------------------------------------

@@ -276,10 +276,19 @@ def test_async_degenerate_matches_sync_trace():
 
 
 def test_censored_fraction():
+    """Over a schedule whose censor fires (the first of 64 keys derived
+    from KEY that has one), censored_fraction is a share in [0, 1], 0 on
+    idle rounds, and survives the list round-trip."""
     packed = _packed("circulant")
-    trace = async_solve_batched(
-        packed, ROUNDS, KEY, config=AsyncGossipConfig(prob=0.5, **CENSOR),
-        return_trace=True)[1]
+    config = AsyncGossipConfig(prob=0.5, **CENSOR)
+    for i in range(64):
+        trace = async_solve_batched(packed, ROUNDS, jax.random.fold_in(KEY, i),
+                                    config=config, return_trace=True)[1]
+        if (np.asarray(trace.active) > np.asarray(trace.broadcasts)).any():
+            break
+    else:
+        pytest.fail("no fold_in(KEY, i), i < 64, gives a schedule whose "
+                    "censor fires")
     active = np.asarray(trace.active)
     censored = active - np.asarray(trace.broadcasts)
     assert censored.sum() > 0, "censor threshold never fired — vacuous"
@@ -551,7 +560,7 @@ def test_instrumented_pack_problem_emits_span():
 
 
 def _tiny_ring_solver():
-    """A 4-node ring at a tiny size, cos_bias DDRF maps (Pallas Gram ok)."""
+    """A 4-node ring at a tiny size, cos_bias DDRF maps."""
     ds, train, _ = cached_split("air_quality", 4, subsample=200, seed=0)
     fmaps = cached_fmaps("air_quality", 4, (6, 8, 6, 8), subsample=200,
                          seed=0)
@@ -574,26 +583,24 @@ def _timeline_events(trace_dir):
 
 def test_timeline_spans_nest_on_profiler_trace(tmp_path):
     """A recorder puts pack_problem's spans on the profiler's host plane:
-    pack.stage, then pack.gram, both inside pack_problem; and each count
+    pack.stage inside pack_problem, with no other span; and each count
     as a marker inside the span that counted it, summing to the
     recorder's count."""
     _, _, solver = _tiny_ring_solver()
-    pack_problem(solver, gram_backend="pallas")         # compile outside
+    pack_problem(solver)                                # compile outside
     with jax.profiler.trace(str(tmp_path)):
         with obs_spans.recording() as rec:
-            pack_problem(solver, gram_backend="pallas")
+            pack_problem(solver)
     assert [(sp.name, sp.parent) for sp in rec.spans] == [
-        ("pack.stage", "pack_problem"), ("pack.gram", "pack_problem"),
-        ("pack_problem", None)]
+        ("pack.stage", "pack_problem"), ("pack_problem", None)]
     pre, cpre = obs_spans.TIMELINE_PREFIX, obs_spans.COUNT_PREFIX
     timeline = _timeline_events(tmp_path)
     events = {n[len(pre):]: (s, e, st) for n, s, e, st in timeline
               if not n.startswith(cpre)}
-    assert set(events) == {"pack_problem", "pack.stage", "pack.gram"}
+    assert set(events) == {"pack_problem", "pack.stage"}
     p0, p1, attrs = events["pack_problem"]
     s0, s1, _ = events["pack.stage"]
-    g0, g1, _ = events["pack.gram"]
-    assert p0 <= s0 < s1 <= g0 < g1 <= p1
+    assert p0 <= s0 < s1 <= p1
     assert attrs == {"nodes": 4, "method": "batched"}
     marks = [(n[len(cpre):], s, st["n"]) for n, s, _, st in timeline
              if n.startswith(cpre)]
@@ -626,32 +633,29 @@ def _pack_table_nbytes(solver) -> int:
     return j_nodes * k_slots * (4 + item) + 5 * j_nodes * item
 
 
-@pytest.mark.parametrize("gram_backend", ["xla", "pallas"])
-def test_pack_uploads_each_nodes_data_once(gram_backend):
+def test_pack_uploads_each_nodes_data_once():
     """Where every padded axis is live, pack.h2d_bytes is the nodes'
     logical x and y plus the small tables: no padded or per-slot copy
     crosses, so it is far from the K-fold neighbour copies it once held."""
     solver = _imbalanced_solver(host_data=True)
     logical = sum(nd.x.nbytes + nd.y.nbytes for nd in solver.data)
-    pack_problem(solver, gram_backend=gram_backend)    # compile outside
+    pack_problem(solver)                               # compile outside
     with obs_spans.recording() as rec:
-        pack_problem(solver, gram_backend=gram_backend)
+        pack_problem(solver)
     sent = rec.counts["pack.h2d_bytes"]
     assert sent == logical + _pack_table_nbytes(solver)
     assert sent < 2 * logical
     assert rec.counts[obs_spans.COMPILES] == 0
 
 
-@pytest.mark.parametrize("gram_backend", ["xla", "pallas"])
-def test_batched_pack_matches_pernode_loop_on_padded_layout(gram_backend):
+def test_batched_pack_matches_pernode_loop_on_padded_layout():
     """On the imbalanced layout (masks live on every axis) the batched
-    pack equals its batch-of-1 per-node replay bit for bit, with either
-    Gram backend."""
+    pack equals its batch-of-1 per-node replay bit for bit."""
     from repro.dist.dekrr_spmd import _pack_problem_pernode
 
     solver = _imbalanced_solver(host_data=True)
-    packed = pack_problem(solver, gram_backend=gram_backend)
-    loop = _pack_problem_pernode(solver, gram_backend=gram_backend)
+    packed = pack_problem(solver)
+    loop = _pack_problem_pernode(solver)
     for name in ("g", "d", "s", "p", "theta_mask", "nbr_idx", "nbr_mask"):
         np.testing.assert_array_equal(
             np.asarray(getattr(packed, name)), np.asarray(getattr(loop, name)),
@@ -672,7 +676,7 @@ def test_device_staged_inputs_equal_numpy_staging():
                                        _stage_packed_inputs)
 
     solver = _imbalanced_solver(host_data=True)
-    staged = _stage_packed_inputs(solver, gram_backend="xla")
+    staged = _stage_packed_inputs(solver)
     dtype = solver.data[0].x.dtype
     sizes = [nd.num_samples for nd in solver.data]
     j_nodes, n_max, dim = solver.J, max(sizes), solver.data[0].x.shape[0]
@@ -707,8 +711,7 @@ def test_device_staged_inputs_equal_numpy_staging():
         np.testing.assert_array_equal(got, ref, err_msg=name + "_n")
 
 
-@pytest.mark.parametrize("gram_backend", ["xla", "pallas"])
-def test_pack_and_solve_spans_state_the_padded_shape(tmp_path, gram_backend):
+def test_pack_and_solve_spans_state_the_padded_shape(tmp_path):
     """pack.stage states the layout it padded to (nodes, slots, f_max,
     d_max, n_max, as the staged arrays have them) and solve.batched the
     packed shape each round streams (nodes, slots, d_max), on the
@@ -716,12 +719,12 @@ def test_pack_and_solve_spans_state_the_padded_shape(tmp_path, gram_backend):
     from repro.dist.dekrr_spmd import _stage_packed_inputs
 
     solver = _imbalanced_solver()
-    staged = _stage_packed_inputs(solver, gram_backend=gram_backend)
-    packed = pack_problem(solver, gram_backend=gram_backend)
+    staged = _stage_packed_inputs(solver)
+    packed = pack_problem(solver)
     solve_batched(packed, 5)                            # compile outside
     with jax.profiler.trace(str(tmp_path)):
         with obs_spans.recording() as rec:
-            packed = pack_problem(solver, gram_backend=gram_backend)
+            packed = pack_problem(solver)
             solve_batched(packed, 5)
     j_nodes, k_slots = staged["nbr_mask"].shape
     stage = dict(nodes=j_nodes, slots=k_slots,
@@ -808,26 +811,24 @@ def test_compiles_count_a_forced_recompile():
     assert rec.counts[obs_spans.COMPILES] == 2
 
 
-@pytest.mark.parametrize("gram_backend", ["xla", "pallas"])
-def test_pack_h2d_bytes_equal_staged_nbytes(gram_backend):
+def test_pack_h2d_bytes_equal_staged_nbytes():
     """pack.h2d_bytes is every numpy byte the batched pack uploads: each
     node's numpy x and y once, at its own size, and the small tables, in
-    one upload with either Gram backend (padding, slot gathers and the
-    Gram pass's inputs are formed on the device); ddrf.h2d_bytes is x and
-    y when they arrive as numpy."""
+    one upload (padding and slot gathers are formed on the device);
+    ddrf.h2d_bytes is x and y when they arrive as numpy."""
     from repro.core import select_features
 
     ds, train, solver = _tiny_ring_solver()
     host = [NodeData(x=np.asarray(t.x), y=np.asarray(t.y)) for t in train]
     want = _pack_table_nbytes(solver)
     with obs_spans.recording() as rec:
-        pack_problem(solver, gram_backend=gram_backend)  # data on device
+        pack_problem(solver)                           # data on device
     assert rec.counts["pack.h2d_bytes"] == want
     solver = DeKRRSolver(solver.topology, solver.feature_maps, host,
                          solver.config, build_aux=False)
     want += sum(nd.x.nbytes + nd.y.nbytes for nd in host)
     with obs_spans.recording() as rec:
-        pack_problem(solver, gram_backend=gram_backend)
+        pack_problem(solver)
     assert rec.counts["pack.h2d_bytes"] == want
 
     x, y = np.asarray(train[0].x), np.asarray(train[0].y)

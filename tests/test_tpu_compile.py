@@ -100,14 +100,15 @@ def test_dekrr_kernel_compiles_for_v5e(one_chip, kernel, dy):
 
 
 def test_rff_gram_compiles_for_v5e(one_chip):
-    """The batched Eq. 17 Gram kernel `pack_problem` runs on the TPU."""
+    """The per-node Eq. 17 Gram kernel that a ragged solver takes as its
+    `gram_fn` (`ops.gram_fn_for_solver`), at one node's Table 2 size."""
     hlo = _compile(
         one_chip,
-        lambda om, b, x, y, m: ops.rff_gram_batched(om, b, x, y, m,
-                                                    interpret=False),
-        ((J, 130, D_IN), F32), ((J, 130), F32), ((J, D_IN, N_NODE), F32),
-        ((J, N_NODE), F32), ((J, N_NODE), F32))
-    assert "tpu_custom_call" in hlo
+        lambda om, b, x, y: ops.rff_gram(om, b, x, y, scale=0.124,
+                                         interpret=False),
+        ((130, D_IN), F32), ((130,), F32), ((D_IN, N_NODE), F32),
+        ((N_NODE,), F32))
+    assert hlo.count("tpu_custom_call") == 1
 
 
 def test_rff_features_compiles_for_v5e(one_chip):
@@ -146,9 +147,8 @@ def _f64_call(kernel: str):
             f(j, d, d), f(j, d), f(j, d, d), f(j, k, d, d), f(j, d),
             f(j, d), idx, self_idx, idx, f(r), f(r), interpret=False)
     if kernel == "rff_gram":
-        return lambda: ops.rff_gram_batched(f(j, d, 3), f(j, d), f(j, 3, 16),
-                                            f(j, 16), f(j, 16),
-                                            interpret=False)
+        return lambda: ops.rff_gram(f(d, 3), f(d), f(3, 16), f(16),
+                                    scale=1.0, interpret=False)
     if kernel == "rff_features":
         return lambda: ops.rff_features(f(d, 3), f(d), f(3, 16), scale=1.0,
                                         interpret=False)
